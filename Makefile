@@ -145,8 +145,8 @@ native-sanitize:
 bench:
 	JAX_PLATFORMS=cpu $(PY) bench.py
 
-# The trajectory gate: trend table over the committed BENCH_*.json
-# series, exit 1 when the latest round regresses past a declared
+# The trajectory gate: trend table over the BENCH_*.json series in the
+# repo root, exit 1 when the latest round regresses past a declared
 # threshold vs its same-backend predecessor.
 bench-report:
 	$(PY) -m jepsen_tpu.cli bench-report

@@ -983,18 +983,16 @@ def bench_north_star(n_dev: int, devices) -> dict:
                 rounds, rounds_src = 5.0, f"fallback: {e!r}"[:120]
         # peak throughput of the formulation the sweep ACTUALLY ran:
         # the auto default is the int8 closure (resolve_formulation).
-        # The peak itself now comes from the device_kind-keyed table
-        # (kernels.device_peak) instead of hard-coded v5e numbers —
-        # on an unknown/CPU device the v5e row still applies, but the
-        # artifact SAYS so (`peak` block below: source "fallback")
-        # instead of silently assuming. BENCH_PEAK_TFLOPS overrides.
+        # The peak comes from the device_kind-keyed table
+        # (kernels.device_peak); a CPU run has none, and no MFU.
+        # BENCH_PEAK_TFLOPS overrides.
         use_pallas_f, use_int8_f = K_.resolve_formulation(
             single_device=mesh is None)
-        peak_row = K_.device_peak()
-        peak_tflops = (peak_row["int8_tops"] if use_int8_f
-                       else peak_row["bf16_tflops"])
+        peak_row = K_.device_peak() if accel else None
         peak = float(os.environ.get(
-            "BENCH_PEAK_TFLOPS", peak_tflops)) * 1e12
+            "BENCH_PEAK_TFLOPS",
+            (peak_row["int8_tops"] if use_int8_f
+             else peak_row["bf16_tflops"]) if peak_row else 0)) * 1e12
         mfu = (B * rounds * 2 * t_pad ** 3) / (t_check * peak * n_dev) \
             if accel else None
         formulation = (("pallas" if use_pallas_f else "xla")
@@ -1105,12 +1103,12 @@ def bench_north_star(n_dev: int, devices) -> dict:
                          f"{'int8' if use_int8_f else 'bf16'} ops, "
                          f"peak {peak / 1e12:g} "
                          f"{'TOPS' if use_int8_f else 'TFLOPS'}/chip",
-            # which peak the MFU denominator used — device_kind-keyed
-            # table row, or the documented v5e fallback, never silent
+            # which peak the MFU denominator used (none on CPU)
             "peak": {"device_kind": peak_row["device_kind"],
                      "source": peak_row["source"],
                      "tflops_used": round(peak / 1e12, 1),
-                     "hbm_gbps": peak_row["hbm_gbps"]},
+                     "hbm_gbps": peak_row["hbm_gbps"]}
+            if peak_row else None,
             # the cost observatory's achieved-bandwidth roofline for
             # this round (estimated-provenance rounds carry "error":
             # an outage to bench-report, not a zero)
@@ -1805,7 +1803,7 @@ def run_benches() -> int:
         print(f"init_distributed failed; continuing single-process: "
               f"{e!r}"[:200], file=sys.stderr)
     try:
-        devices = devmod.default_devices(probe=True)
+        devices = devmod.default_devices()
     except Exception as e:
         print(json.dumps({
             "metric": "elle-append histories/sec", "value": 0.0,
@@ -1823,8 +1821,6 @@ def run_benches() -> int:
                "value": 0.0, "unit": "histories/sec", "vs_baseline": 0.0,
                "error": repr(e)[:300]}
     out["backend"] = platform
-    if devmod.backend_error:
-        out["tpu_error"] = devmod.backend_error
     # failure injection for supervisor tests; scoped to the primary
     # attempt so the CPU retry demonstrates the backfill
     force_fail = set() if os.environ.get("BENCH_ATTEMPT") == "cpu-retry" \

@@ -13,18 +13,22 @@ with two layers:
     tracing), and
   * a disk cache of serialized executables
     (`jax.experimental.serialize_executable`), keyed by a digest of
-    (jax/jaxlib version, backend platform + device count, input
-    avals, kernel flags, formulation), so a REPEAT sweep in a fresh
-    process deserializes instead of compiling.
+    (jax/jaxlib version, backend platform + device count, jitted
+    function name, input avals and shardings, kernel flags,
+    formulation), so a REPEAT sweep in a fresh process deserializes
+    instead of compiling.
 
 Every lookup lands in exactly one of the `compile_cache_hits` /
 `compile_cache_misses` counters — the warm-path bench drives the miss
 count to zero and `make bench-warm` gates on it. Everything here is
 best-effort: a corrupt/incompatible cache entry (jax upgrade, topology
 change — both keyed, but belt and braces) degrades to a fresh compile,
-never to a failed sweep. Gates: `JEPSEN_TPU_AOT_CACHE` (default on),
-`JEPSEN_TPU_COMPILE_CACHE_DIR` (default `~/.cache/jepsen_tpu/
-executables`).
+never to a failed sweep. Gate: `JEPSEN_TPU_AOT_CACHE` (default on).
+
+One compile cache, placed from outside: JAX's persistent compilation
+cache and the serialized executables (in its `executables/`
+subdirectory) both live under `JAX_COMPILATION_CACHE_DIR` when it is
+set, and under the fixed, git-ignored `<repo>/.jax_cache` otherwise.
 """
 
 from __future__ import annotations
@@ -52,14 +56,33 @@ def enabled() -> bool:
     return gates.get("JEPSEN_TPU_AOT_CACHE")
 
 
+#: The compile cache's home when JAX_COMPILATION_CACHE_DIR is unset:
+#: fixed and inside the checkout, so a later process finds it again.
+DEFAULT_CACHE_ROOT = Path(__file__).resolve().parent.parent / ".jax_cache"
+
+
+def cache_root() -> Path:
+    """Where every compile artifact lives: JAX_COMPILATION_CACHE_DIR,
+    else DEFAULT_CACHE_ROOT."""
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    return Path(d) if d else DEFAULT_CACHE_ROOT
+
+
 def cache_dir() -> Path:
-    """The on-disk executable cache directory
-    (JEPSEN_TPU_COMPILE_CACHE_DIR overrides the default)."""
-    from . import gates
-    d = gates.get("JEPSEN_TPU_COMPILE_CACHE_DIR")
-    if d:
-        return Path(d)
-    return Path.home() / ".cache" / "jepsen_tpu" / "executables"
+    """The on-disk serialized-executable cache directory."""
+    return cache_root() / "executables"
+
+
+def configure_jax_cache() -> Path:
+    """Point JAX's persistent compilation cache at cache_root(). JAX
+    reads JAX_COMPILATION_CACHE_DIR itself, so this only sets the
+    in-checkout default; call it before the process's first compile
+    (the sweep and daemon entry points do)."""
+    import jax
+    root = cache_root()
+    if jax.config.jax_compilation_cache_dir != str(root):
+        jax.config.update("jax_compilation_cache_dir", str(root))
+    return root
 
 
 def clear_memory() -> None:
@@ -76,30 +99,49 @@ def resident_count() -> int:
         return len(_mem)
 
 
-def _fingerprint(args, key_parts: tuple) -> str:
+def _placement(a) -> list:
+    """The devices an argument lives on, in the executable's device
+    order (a mesh's, else by id)."""
+    sh = a.sharding
+    mesh = getattr(sh, "mesh", None)
+    if mesh is not None:
+        return list(mesh.devices.flat)
+    return sorted(sh.device_set, key=lambda d: d.id)
+
+
+def _sharding_key(a) -> str:
+    """An argument's placement: sharding type, device ids and spec. An
+    executable compiled for 8-shard inputs cannot run 1-device ones."""
+    sh = a.sharding
+    ids = [d.id for d in _placement(a)]
+    return f"{type(sh).__name__}{ids}{getattr(sh, 'spec', '')}"
+
+
+def _fingerprint(jitfn, args, key_parts: tuple) -> str:
     """Digest of everything that determines the compiled artifact:
-    toolchain versions, backend topology, input avals, kernel flags."""
+    toolchain versions, backend topology, the jitted function, input
+    avals and shardings, kernel flags."""
     import jax
-    try:
-        import jaxlib
-        jaxlib_v = jaxlib.__version__
-    except Exception:
-        jaxlib_v = ""
-    backend = jax.devices()[0].platform if jax.devices() else "none"
-    parts = [jax.__version__, jaxlib_v,
-             backend, str(jax.device_count()), repr(key_parts)]
+    import jaxlib
+    parts = [jax.__version__, jaxlib.__version__,
+             jax.devices()[0].platform, str(jax.device_count()),
+             getattr(jitfn, "__name__", type(jitfn).__name__),
+             repr(key_parts)]
     for a in args:
-        parts.append(f"{tuple(a.shape)}:{a.dtype}")
+        parts.append(f"{tuple(a.shape)}:{a.dtype}:{_sharding_key(a)}")
     return hashlib.sha256("|".join(map(str, parts)).encode()).hexdigest()
 
 
-def _disk_load(path: Path):
-    """Deserialize one cached executable, or None (missing/corrupt/
-    incompatible — the caller recompiles and overwrites)."""
+def _disk_load(path: Path, devices: list):
+    """Deserialize one cached executable onto `devices` (the ones it
+    was compiled for — left unnamed, JAX loads it onto every local
+    device), or None (missing/corrupt/incompatible — the caller
+    recompiles and overwrites)."""
     try:
         from jax.experimental import serialize_executable as se
         payload, in_tree, out_tree = pickle.loads(path.read_bytes())
-        return se.deserialize_and_load(payload, in_tree, out_tree)
+        return se.deserialize_and_load(payload, in_tree, out_tree,
+                                       execution_devices=devices)
     except FileNotFoundError:
         return None
     except Exception:
@@ -130,7 +172,7 @@ def compiled_for(jitfn, args, key_parts: tuple):
     sweep must never be hostage to its own compile cache."""
     from . import trace
     try:
-        key = _fingerprint(args, key_parts)
+        key = _fingerprint(jitfn, args, key_parts)
         with _lock:
             hit = _mem.get(key)
         if hit is not None:
@@ -143,7 +185,7 @@ def compiled_for(jitfn, args, key_parts: tuple):
             device_obs.observe(key_parts, args, hit, source="compiled")
             return hit
         path = cache_dir() / f"{key}.jtx"
-        compiled = _disk_load(path)
+        compiled = _disk_load(path, _placement(args[0]))
         if compiled is not None:
             trace.counter("compile_cache_hits").inc()
         else:

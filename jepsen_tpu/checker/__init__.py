@@ -605,9 +605,8 @@ class Linearizable(Checker):
         semantics from a nil initial state, so any other model routes
         to CPU wholesale. Verdicts only ever degrade toward the
         oracle, never diverge from it."""
-        # Model eligibility first: resolving an auto backend may probe
-        # the hardware (bounded, but up to JEPSEN_TPU_PROBE_TIMEOUT on a
-        # dead transport) — pointless when only the CPU path can apply.
+        # Model eligibility first: only the CPU engine implements
+        # models other than the nil-initial CAS register.
         def cpu_all():
             out = []
             for hs in histories:
@@ -686,7 +685,7 @@ class Linearizable(Checker):
         def dev_side():
             try:
                 dev_out.append(self._device_batch(histories))
-            except Exception as e:   # device failure: CPU decides
+            except Exception as e:   # re-raised by the main thread
                 dev_out.append(e)
             finally:
                 Linearizable._race_threads.discard(
@@ -713,21 +712,22 @@ class Linearizable(Checker):
         while True:
             turn.wait()
             turn.clear()
-            dev_ok = (dev_done.is_set() and dev_out
-                      and not isinstance(dev_out[0], Exception))
-            if dev_ok:
+            if dev_done.is_set():
                 stop.set()
+                if isinstance(dev_out[0], Exception):
+                    # a failed device side fails the race: the CPU
+                    # engine never stands in for a broken device
+                    raise dev_out[0]
                 return dev_out[0]
             if cpu_done.is_set():
                 if cpu_exc:
                     # CPU side failed; the device result decides, or
                     # the failure propagates as it would un-raced
                     dev_done.wait()
-                    if dev_out and not isinstance(dev_out[0], Exception):
-                        return dev_out[0]
-                    raise cpu_exc[0]
+                    if isinstance(dev_out[0], Exception):
+                        raise dev_out[0]
+                    return dev_out[0]
                 return list(cpu_res)
-            # device errored first: wait for the CPU side to finish
 
     def _device_batch(self, histories: list[list],
                       stats_out: list | None = None) -> list[dict]:
@@ -793,6 +793,9 @@ class Linearizable(Checker):
                     results[i] = r
                     if fs is not None:
                         stats[i] = fs[j]
+        if cpu_idx:
+            from .. import trace
+            trace.counter("register_cpu_routed").inc(len(cpu_idx))
         for i in cpu_idx:
             sd: dict | None = {} if with_stats else None
             results[i] = self._cpu(histories[i], search_stats=sd)

@@ -32,9 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...devices import default_devices, ensure_platform_pin
-
-ensure_platform_pin()
+from ...devices import default_devices
 from ...util import pad_to_multiple
 from .encode import EncodedHistory, effective_complete_index
 
@@ -94,14 +92,11 @@ def stats_row(row, *, n_txns: int, t_pad: int) -> dict:
     out["pad_waste_cells"] = int(t_pad) ** 2 - int(n_txns) ** 2
     return out
 
-#: Per-chip peak throughput, keyed by a normalized `device_kind`. The
-#: MFU/roofline numbers used to assume v5e (394 int8 TOPS hard-coded in
-#: bench.py) whatever chip actually ran; now the peak resolves from
-#: `jax.devices()[0].device_kind` with the v5e row as the DOCUMENTED
-#: fallback — and every consumer (bench artifact, costdb record, report
-#: device section) surfaces WHICH peak it used (`source: table` vs
-#: `fallback`), so an assumed number can never read as a measured one.
-#: Values are the published per-chip peaks: dense bf16 TFLOPS, int8
+#: Per-chip peak throughput, keyed by a normalized `device_kind`
+#: (`jax.devices()[0].device_kind`). A kind missing from the table is
+#: an error, never a default: host CPUs and unknown chips have no peak,
+#: and their callers ask for none. Values are the published per-chip
+#: peaks: dense bf16 TFLOPS, int8
 #: TOPS (chips without an int8 fast path reuse the bf16 number — the
 #: closure is exact in either arithmetic, see _closure_batched), HBM
 #: bandwidth GB/s and capacity GiB.
@@ -124,31 +119,19 @@ DEVICE_PEAKS: dict[str, dict] = {
 _PEAK_ALIASES = {"tpu v5e": "tpu v5 lite", "tpu v5": "tpu v5p",
                  "tpu v6e": "tpu v6 lite", "tpu v6": "tpu v6 lite"}
 
-#: The documented fallback row for unknown/CPU device kinds — the v5e
-#: values every pre-peak-table number assumed.
-_PEAK_FALLBACK = "tpu v5 lite"
-
-
 def device_peak(device_kind: str | None = None) -> dict:
     """The peak-throughput row for `device_kind` (default: the first
-    jax device's), plus `device_kind` (as reported) and `source`:
-    `"table"` for a known chip, `"fallback"` when the kind is unknown
-    (CPU hosts, new chips) and the v5e row is assumed — consumers must
-    surface that instead of publishing an assumed peak as measured."""
+    jax device's), plus `device_kind` (as reported) and `source`.
+    Raises KeyError for a kind the table does not hold."""
     if device_kind is None:
-        try:
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            device_kind = "unknown"
+        device_kind = jax.devices()[0].device_kind
     norm = str(device_kind).strip().lower()
     norm = _PEAK_ALIASES.get(norm, norm)
     row = DEVICE_PEAKS.get(norm)
-    if row is not None:
-        return {"device_kind": str(device_kind), "source": "table",
-                **row}
-    return {"device_kind": str(device_kind),
-            "source": f"fallback (assumed {_PEAK_FALLBACK})",
-            **DEVICE_PEAKS[_PEAK_FALLBACK]}
+    if row is None:
+        raise KeyError(f"no peak-throughput row for device kind "
+                       f"{device_kind!r}")
+    return {"device_kind": str(device_kind), "source": "table", **row}
 
 
 def pad_to(x: int, multiple: int) -> int:
@@ -274,13 +257,11 @@ def resolve_formulation(use_pallas: bool | None = None,
     not just the bench. Explicit arguments win; the env picks the
     default: "bf16" / "int8" pin the XLA formulations, "pallas" /
     "pallas-int8" opt into the fused ones. The auto default is the
-    XLA **int8** matmul pipeline — int8 won the four-way race on real
-    v5e hardware AND on CPU (BENCH_r05_hw; the closure is exact in
-    either arithmetic), and XLA beat the fused Pallas kernels at every
-    production shape. Pallas needs a single-device dispatch (sharded closures
-    stay XLA for the collectives) and a per-VARIANT lowering probe —
-    an int8-specific Mosaic regression degrades to the XLA matmul
-    instead of breaking production."""
+    XLA **int8** matmul pipeline (the closure is exact in either
+    arithmetic; int8 has twice the MXU rate of bf16 on v5e). Pallas
+    needs a single-device dispatch (sharded closures stay XLA for the
+    collectives) and a lowering probe that raises when the kernel does
+    not lower or miscomputes."""
     from ... import gates
 
     from . import pallas_square
@@ -289,23 +270,17 @@ def resolve_formulation(use_pallas: bool | None = None,
     env = gates.get("JEPSEN_TPU_CLOSURE")
     if use_int8 is None:
         # auto default is int8: the boolean closure is exact in either
-        # arithmetic, and int8 won the race on BOTH measured backends —
-        # real v5e (74.3 vs 68.6 hist/s at the 5k-txn headline,
-        # BENCH_r05_hw) and CPU (1.5x at T=1024) — which the MXU's 2:1
-        # int8:bf16 throughput predicts. JEPSEN_TPU_CLOSURE=bf16 pins
-        # the old formulation.
+        # arithmetic, and the MXU runs int8 at twice the bf16 rate.
+        # JEPSEN_TPU_CLOSURE=bf16 pins the old formulation.
         use_int8 = env in ("int8", "pallas-int8") if env else True
     if use_pallas is None:
         if env in ("pallas", "pallas-int8") and single_device:
-            # explicit opt-in only: fuse when it lowers
+            # explicit opt-in only; a kernel that does not lower raises
             use_pallas = pallas_square.pallas_available(int8=use_int8)
         else:
-            # auto default is the XLA matmul pipeline: on a real v5e
-            # the fused Pallas squaring measured 23 hist/s vs XLA's
-            # 65-74 at the 5000-txn headline shape (and lost at 1000,
-            # tied at 300) — XLA's own tiling beats the hand kernel
-            # at every production shape, so fusion stays an explicit
-            # JEPSEN_TPU_CLOSURE=pallas[-int8] experiment
+            # auto default is the XLA matmul pipeline; fusion stays an
+            # explicit JEPSEN_TPU_CLOSURE=pallas[-int8] experiment until
+            # a chip cell shows it winning
             use_pallas = False
     return bool(use_pallas), bool(use_int8)
 

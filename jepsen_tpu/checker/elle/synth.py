@@ -169,7 +169,7 @@ def synth_append_history(T: int, K: int, seed: int = 0,
 
 
 def write_synth_store(root, B: int, T: int, K: int,
-                      bad_every: int) -> list:
+                      bad_every: int, seed: int = 0) -> list:
     """Materialize B serial list-append runs as history.jsonl dirs —
     the same execution shape as synth_encoded_history (txn i appends
     (key (i+rot)%K, pos i//K+1) and externally reads a key it has
@@ -179,12 +179,13 @@ def write_synth_store(root, B: int, T: int, K: int,
     G1c cycle for the classify pass to find, with no same-txn read
     that would trip the encoder's `internal` check instead. The ONE
     synthetic-store generator, shared by bench.py's north-star block
-    and the `make bench-warm` gate so the two can't drift."""
+    and the `make bench-warm` gate so the two can't drift. `seed`
+    shifts every history's key rotation."""
     from pathlib import Path
     root = Path(root)
     dirs = []
     for h in range(B):
-        rot = h % K
+        rot = (h + seed) % K
         corrupt = bad_every and h % bad_every == bad_every - 1
         a = T // 2
         lines = []

@@ -47,9 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...devices import default_devices, ensure_platform_pin
-
-ensure_platform_pin()
+from ...devices import default_devices
 from ...util import pad_to_multiple
 from .encode import CAS, READ, WRITE, EncodingError, _reduced_seq
 

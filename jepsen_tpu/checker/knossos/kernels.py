@@ -54,9 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...devices import default_devices, ensure_platform_pin
-
-ensure_platform_pin()
+from ...devices import default_devices
 from ...util import pad_to_multiple
 from .encode import (CAS, COMPLETE_EV, INVOKE_EV, READ, WRITE,
                      EncodedRegisterHistory, RegisterBatchShape,

@@ -29,9 +29,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ...devices import ensure_platform_pin
-
-ensure_platform_pin()
 from .kernels import _BIG, _step_register
 from .encode import COMPLETE_EV, INVOKE_EV
 

@@ -461,6 +461,7 @@ def analyze_store(store: Store, checker: str = "append",
     reports is LOST (exit ≥2, runs unverdicted) and re-assignable
     with `JEPSEN_TPU_MESH_SHARD=<k> --mesh --resume` — the
     supervisor's degradation contract at fleet scale."""
+    from . import aot
     from . import mesh as meshmod
     from . import obs
     from . import shm as _shm
@@ -468,6 +469,7 @@ def analyze_store(store: Store, checker: str = "append",
     from .obs import device as device_obs
     from .obs import search as search_obs
     from .store import VerdictJournal, analytics_path, costdb_path
+    aot.configure_jax_cache()
     if report is None:
         report = gates.get("JEPSEN_TPU_REPORT")
     if mesh is None:
@@ -819,11 +821,11 @@ def _analyze_store_impl(store: Store, checker: str = "append",
 
     # Pipelining decision passed DOWN to iter_encode_chunks, not via
     # process-global env (a later sweep or embedded caller must not
-    # inherit a stale accelerator probe). None = let ingest decide.
+    # inherit a stale decision). None = let ingest decide.
     sweep_procs = None
     if not host_only:
         from . import devices as devmod
-        if devmod.accelerator_available():   # probe-bounded, jax-free
+        if devmod.accelerator_available():
             # overlap pays even on a single-core host when a real
             # device runs the checks: the worker parses while the
             # parent blocks on the accelerator (append AND wr sweeps)
@@ -837,15 +839,12 @@ def _analyze_store_impl(store: Store, checker: str = "append",
 
         def get_mesh():
             if not mesh_box:
-                try:
-                    # a mesh-sweep shard dispatches on ITS OWN host's
-                    # chips only: the cross-host axis is the shard
-                    # split of run dirs, never a global dispatch mesh
-                    mesh_box.append(parallel.host_local_mesh()
-                                    if shard is not None
-                                    else parallel.make_mesh())
-                except Exception:
-                    mesh_box.append(None)
+                # a mesh-sweep shard dispatches on ITS OWN host's
+                # chips only: the cross-host axis is the shard split
+                # of run dirs, never a global dispatch mesh
+                mesh_box.append(parallel.host_local_mesh()
+                                if shard is not None
+                                else parallel.make_mesh())
             return mesh_box[0]
 
         # The checker class's own defaults, so batch verdicts match
@@ -1183,7 +1182,10 @@ def _stored_fallback(d, stored_check, checker: str | None = None,
     """Run a dir through its own stored checker, quarantining (an
     `unknown` verdict, never an exception, never a dead sweep) on
     failure. With `checker`, a success leaves the `.sweep-<checker>`
-    sidecar so --resume counts the run done for that sweep."""
+    sidecar so --resume counts the run done for that sweep. Every run a
+    device sweep routes here counts in `stored_fallbacks`."""
+    if checker != "stored":
+        trace.get_current().counter("stored_fallbacks").inc()
     try:
         res = stored_check(d)
     except Exception as e:

@@ -211,16 +211,17 @@ def _load_worker(run_dir):
 
 def _spawn_safe() -> bool:
     """Can a spawn-context worker actually boot? spawn re-imports
-    __main__; when __main__ has no importable file (stdin scripts, a
-    REPL, embedded interpreters) every worker dies during bootstrap
-    and the pool respawns replacements forever — the parent then hangs
-    in imap instead of falling back. Detect that case up front."""
+    __main__ by its spec or, failing that, its `__file__`; when that
+    file does not exist (stdin scripts) every worker dies during
+    bootstrap and the pool respawns replacements forever — the parent
+    then hangs in imap instead of falling back. Detect that case up
+    front. A __main__ with neither (a REPL, `python -c`, pytest-xdist
+    workers) is not re-imported at all: safe."""
     import sys
     m = sys.modules.get("__main__")
     f = getattr(m, "__file__", None)
-    if f is None:
-        # `python -m pkg.mod` has a spec instead of a file: fine
-        return getattr(m, "__spec__", None) is not None
+    if f is None or getattr(m, "__spec__", None) is not None:
+        return True
     return os.path.exists(f)
 
 

@@ -478,7 +478,8 @@ STORE_ARTIFACTS: tuple[StoreArtifact, ...] = (
         retention="replaced",
         root="cache",
         doc="serialized XLA executables under "
-            "`~/.cache/jepsen_tpu/executables`; corrupt entries "
+            "`$JAX_COMPILATION_CACHE_DIR/executables` (default "
+            "`<repo>/.jax_cache/executables`); corrupt entries "
             "degrade to a fresh compile"),
     StoreArtifact(
         "jax profile capture", ("jax-profile",), "sidecar",

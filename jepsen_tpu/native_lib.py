@@ -52,8 +52,8 @@ def _compile_so(src: Path, so: Path) -> bool:
 def _load_so(src: Path, so: Path) -> ctypes.CDLL | None:
     """Shared build-or-rebuild-then-dlopen recipe: honor the
     JEPSEN_TPU_NO_NATIVE kill switch, rebuild when the source is newer
-    than the lib, tolerate a failed rebuild if a stale lib still loads,
-    and degrade to None on any failure."""
+    than the lib, and degrade to None on any failure — a failed
+    rebuild included: a lib older than its source is never loaded."""
     from . import gates
     if gates.get("JEPSEN_TPU_NO_NATIVE"):
         return None
@@ -61,8 +61,7 @@ def _load_so(src: Path, so: Path) -> ctypes.CDLL | None:
              and src.stat().st_mtime > so.stat().st_mtime)
     if (not so.exists() or stale) and not (src.exists()
                                            and _compile_so(src, so)):
-        if not so.exists():
-            return None  # a stale lib still loads; no lib doesn't
+        return None
     try:
         return ctypes.CDLL(str(so))
     except OSError as e:

@@ -123,16 +123,13 @@ def dispatch_cost_key(kw: dict, shape, single_device: bool,
 
 
 def _cost_dict(obj) -> dict | None:
-    """Normalized `cost_analysis()` of a Compiled/Lowered, or None.
-    jax returns a single dict or a one-element list depending on
-    version; keys of interest are `flops`, `bytes accessed` and
-    `transcendentals`."""
+    """Normalized `cost_analysis()` of a Compiled/Lowered, or None
+    (some deserialized executables have none); keys of interest are
+    `flops`, `bytes accessed` and `transcendentals`."""
     try:
         ca = obj.cost_analysis()
     except Exception:
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict):
         return None
 
@@ -367,10 +364,11 @@ def _finalize(rec: dict) -> dict:
     out = {k: v for k, v in rec.items() if k != "key_parts"}
     out = {"v": 1, **out}
     w = rec["windows"]
-    peak = K.device_peak(rec.get("device_kind"))
+    on_device = rec.get("backend") not in ("cpu", "unknown")
+    # a host CPU has no peak row: its records carry no roofline
+    peak = K.device_peak(rec.get("device_kind")) if on_device else None
     out["peak"] = peak
-    measured = w["dispatches"] > 0 and rec.get("backend") \
-        not in ("cpu", "unknown")
+    measured = w["dispatches"] > 0 and on_device
     out["provenance"] = "measured" if measured else "estimated"
     cost = rec.get("cost") or {}
     achieved = {"flops_per_sec": None, "bytes_per_sec": None}
@@ -379,15 +377,18 @@ def _finalize(rec: dict) -> dict:
         per_sec = w["dispatches"] / w["device_secs"]
         if isinstance(cost.get("flops"), (int, float)):
             achieved["flops_per_sec"] = cost["flops"] * per_sec
-            peak_ops = (peak["int8_tops"] if "int8" in
-                        (rec.get("formulation") or "")
-                        else peak["bf16_tflops"]) * 1e12
-            roofline["flops_utilization"] = round(
-                achieved["flops_per_sec"] / peak_ops, 6)
+            if peak is not None:
+                peak_ops = (peak["int8_tops"] if "int8" in
+                            (rec.get("formulation") or "")
+                            else peak["bf16_tflops"]) * 1e12
+                roofline["flops_utilization"] = round(
+                    achieved["flops_per_sec"] / peak_ops, 6)
         if isinstance(cost.get("bytes_accessed"), (int, float)):
             achieved["bytes_per_sec"] = cost["bytes_accessed"] * per_sec
-            roofline["bandwidth_utilization"] = round(
-                achieved["bytes_per_sec"] / (peak["hbm_gbps"] * 1e9), 6)
+            if peak is not None:
+                roofline["bandwidth_utilization"] = round(
+                    achieved["bytes_per_sec"]
+                    / (peak["hbm_gbps"] * 1e9), 6)
     out["achieved"] = achieved
     out["roofline"] = roofline
     return out

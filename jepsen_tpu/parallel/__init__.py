@@ -37,10 +37,8 @@ from .. import trace
 from ..obs import device as obs_device
 from ..obs import events as obs_events
 from ..checker.elle import kernels as K
-from ..devices import default_devices, ensure_platform_pin
+from ..devices import default_devices
 from . import residency
-
-ensure_platform_pin()
 from ..util import pad_to_multiple
 
 log = logging.getLogger(__name__)
@@ -91,11 +89,8 @@ def init_distributed() -> bool:
     if not (os.environ.get("JAX_COORDINATOR_ADDRESS")
             or os.environ.get("COORDINATOR_ADDRESS")):
         return False
-    try:
-        if jax._src.distributed.global_state.client is not None:
-            return True  # already initialized
-    except Exception:
-        pass
+    if jax.distributed.is_initialized():
+        return True
     kw = {}
     addr = (os.environ.get("JAX_COORDINATOR_ADDRESS")
             or os.environ.get("COORDINATOR_ADDRESS"))

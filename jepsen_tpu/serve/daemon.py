@@ -217,6 +217,8 @@ class VerdictDaemon:
     def start(self) -> "VerdictDaemon":
         from .. import shm as _shm
         from ..parallel import folding
+        from .. import aot
+        aot.configure_jax_cache()
         base = Path(self.store.base)
         base.mkdir(parents=True, exist_ok=True)
         trace.fresh_run(f"serve:{base.name}", scope="sweep")

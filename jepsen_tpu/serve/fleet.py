@@ -69,7 +69,7 @@ import threading
 import time
 from pathlib import Path
 
-from .. import gates, trace
+from .. import devices, gates, trace
 from .. import store as store_mod
 from ..obs import events as obs_events
 from ..obs import health as obs_health
@@ -117,17 +117,27 @@ def spill_depth() -> int:
     return max(1, int(v)) if v is not None else 32
 
 
+def _device_backed(env: dict) -> bool:
+    """Would a daemon started with `env` put its kernels on an
+    accelerator? Only an explicit CPU-only platform pin says no."""
+    want = env.get("JEPSEN_TPU_PLATFORM") or env.get("JAX_PLATFORMS")
+    plats = {p.strip() for p in (want or "").split(",") if p.strip()}
+    return not plats or not plats <= {"cpu"}
+
+
 class _Member:
     """One fleet daemon as the router sees it: spawned subprocess or
     attached (tests drive in-process daemons), beacon-backed."""
 
     def __init__(self, instance: int, socket_path, beacon_path,
-                 proc=None, pid: int | None = None):
+                 proc=None, pid: int | None = None,
+                 device_backed: bool = False):
         self.instance = int(instance)
         self.socket_path = Path(socket_path)
         self.beacon_path = Path(beacon_path)
         self.proc = proc
         self.pid = pid
+        self.device_backed = device_backed
         self.status = "starting"      # starting -> live -> dead
         self.beacon: dict = {}
         self.beacon_age: float | None = None
@@ -259,8 +269,12 @@ class FleetRouter:
             pass
         self._epoch = 1
         if self.spawn:
-            for k in range(self.daemons):
-                self._spawn_member(k)
+            try:
+                for k in range(self.daemons):
+                    self._spawn_member(k)
+            except Exception:
+                self.stop()
+                raise
         deadline = time.monotonic() + self.start_timeout_s
         for m in list(self._members.values()):
             if not self._wait_member_live(m, deadline):
@@ -391,6 +405,22 @@ class FleetRouter:
             env.pop(var, None)
         env.update({str(a): str(b) for a, b
                     in self.member_env.get(k, {}).items()})
+        backed = _device_backed(env)
+        if backed:
+            # a chip belongs to one process: never start more
+            # device-backed daemons than the host has chips
+            chips = devices.host_chip_count()
+            with self._mlock:
+                others = sum(
+                    1 for j, m in self._members.items()
+                    if j != k and m.device_backed
+                    and (m.proc is None or m.proc.poll() is None))
+            if others + 1 > chips:
+                raise RuntimeError(
+                    f"fleet member d{k} would be device-backed daemon "
+                    f"#{others + 1} on a host with {chips} chip(s); a "
+                    f"chip belongs to one process — run fewer daemons "
+                    f"or pin members to the CPU (JAX_PLATFORMS=cpu)")
         cmd = [sys.executable, "-m", "jepsen_tpu.cli", "serve",
                "--store", str(base), "--socket", str(sock),
                "--fleet-instance", str(k),
@@ -400,7 +430,7 @@ class FleetRouter:
         with self._mlock:
             self._members[k] = _Member(
                 k, sock, store_mod.fleet_member_path(base, k),
-                proc=proc)
+                proc=proc, device_backed=backed)
 
     def _wait_member_live(self, m: _Member, deadline: float) -> bool:
         while time.monotonic() < deadline:

@@ -5,7 +5,7 @@ every EncodedHistory through `multiprocessing.Pool`'s result pipe:
 each worker pickled its arrays, the parent unpickled them SERIALLY on
 the thread that also packs and dispatches to the device — for a
 256x5000-txn sweep that serial unpickle alone is tens of seconds of
-pure copy (the 40 s host gap of BENCH_r05_hw.json). Here workers
+pure copy. Here workers
 instead write the encoded arrays once into a POSIX shared-memory
 segment and send only a tiny descriptor — (segment name, per-field
 offset/shape/dtype) — over the pipe; the parent maps the segment and

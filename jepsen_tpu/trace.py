@@ -70,11 +70,12 @@ DECLARED_METRICS: dict[str, frozenset] = {
         "native_fallback", "oom_retries", "pad_waste_cells",
         "planner.cold_starts", "planner.decisions",
         "planner.fallbacks", "planner.pred_checked",
-        "quarantined", "runs_verdicted",
+        "quarantined", "register_cpu_routed", "runs_verdicted",
         "serve_backpressure", "serve_folds", "serve_replays",
         "serve_requests", "serve_verdicts", "shm_bytes",
         "shm_stale_reclaimed", "sidecar_upgrades", "split.native",
-        "split.python", "warm_copy_bytes", "watchdog_timeouts",
+        "split.python", "stored_fallbacks", "warm_copy_bytes",
+        "watchdog_timeouts",
         "worker_spans",
     }),
     "gauges": frozenset({"donate_slots_inflight", "fleet_daemons_live",

@@ -89,7 +89,7 @@ def main(argv: list[str] | None = None) -> int:
         store_dir.mkdir()
         _write_store(store_dir, B, T, K)
         env = {**os.environ,
-               "JEPSEN_TPU_COMPILE_CACHE_DIR": str(Path(td) / "aot"),
+               "JAX_COMPILATION_CACHE_DIR": str(Path(td) / "cache"),
                "JEPSEN_TPU_TRACE": "1"}
         runs = []
         for name in ("cold", "warm", "warm-again"):

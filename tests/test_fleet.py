@@ -418,3 +418,48 @@ def test_collect_without_reconnect_raises_plain_error(tmp_path,
     c.check_history([], rid="h1")
     with pytest.raises(ServeError, match="closed the connection"):
         c.collect(timeout=30)
+
+
+# ---------------------------------------------------------------------------
+# One process per chip: device-backed members never outnumber the chips
+# ---------------------------------------------------------------------------
+
+def test_spawn_refuses_more_device_members_than_chips(tmp_path,
+                                                      monkeypatch):
+    from jepsen_tpu import devices
+    from jepsen_tpu.serve.fleet import FleetRouter
+    from jepsen_tpu.store import Store
+    monkeypatch.setattr(devices, "host_chip_count", lambda: 0)
+    spawned = []
+    monkeypatch.setattr("subprocess.Popen",
+                        lambda *a, **kw: spawned.append(a))
+    router = FleetRouter(Store(tmp_path / "store"), daemons=1,
+                         member_env={0: {"JAX_PLATFORMS": "tpu",
+                                         "JEPSEN_TPU_PLATFORM": ""}})
+    with pytest.raises(RuntimeError, match="0 chip"):
+        router._spawn_member(0)
+    assert not spawned
+
+
+def test_device_backed_reads_the_platform_pin():
+    from jepsen_tpu.serve.fleet import _device_backed
+    assert _device_backed({}) is True
+    assert _device_backed({"JAX_PLATFORMS": "tpu"}) is True
+    assert _device_backed({"JAX_PLATFORMS": "cpu"}) is False
+    assert _device_backed({"JEPSEN_TPU_PLATFORM": "cpu",
+                           "JAX_PLATFORMS": "tpu"}) is False
+
+
+def test_host_chip_count_reads_pci_accelerators(tmp_path):
+    from jepsen_tpu import devices
+    # two v5e chips as a v5litepod host lists them, a gVNIC (same
+    # vendor, network class) and another vendor's accelerator
+    for name, vendor, cls in (("0000:00:08.0", "0x1ae0", "0xff0000"),
+                              ("0000:00:09.0", "0x1ae0", "0xff0000"),
+                              ("0000:00:06.0", "0x1ae0", "0x020000"),
+                              ("0000:00:07.0", "0x8086", "0x120000")):
+        d = tmp_path / name
+        d.mkdir()
+        (d / "vendor").write_text(vendor + "\n")
+        (d / "class").write_text(cls + "\n")
+    assert devices.host_chip_count(str(tmp_path)) == 2

@@ -41,7 +41,6 @@ ALL_GATES = [
     "JEPSEN_TPU_CLOSURE",
     "JEPSEN_TPU_FUSED_CLASSIFY",
     "JEPSEN_TPU_FRONTIER",
-    "JEPSEN_TPU_PROBE_TIMEOUT",
     "JEPSEN_TPU_NATIVE_INGEST",
     "JEPSEN_TPU_NATIVE_SPLIT",
     "JEPSEN_TPU_NO_NATIVE",
@@ -54,7 +53,6 @@ ALL_GATES = [
     "JEPSEN_TPU_SIDECAR_V2",
     "JEPSEN_TPU_DONATE_BUFFERS",
     "JEPSEN_TPU_AOT_CACHE",
-    "JEPSEN_TPU_COMPILE_CACHE_DIR",
     "JEPSEN_TPU_MESH",
     "JEPSEN_TPU_MESH_SHARD",
     "JEPSEN_TPU_MESH_SHARDS",
@@ -121,10 +119,10 @@ def test_int_malformed_falls_back(monkeypatch):
 
 
 def test_float_malformed_falls_back(monkeypatch):
-    monkeypatch.setenv("JEPSEN_TPU_PROBE_TIMEOUT", "soon")
-    assert gates.get("JEPSEN_TPU_PROBE_TIMEOUT") == 120.0
-    monkeypatch.setenv("JEPSEN_TPU_PROBE_TIMEOUT", "7.5")
-    assert gates.get("JEPSEN_TPU_PROBE_TIMEOUT") == 7.5
+    monkeypatch.setenv("JEPSEN_TPU_MESH_WAIT_S", "soon")
+    assert gates.get("JEPSEN_TPU_MESH_WAIT_S") == 600.0
+    monkeypatch.setenv("JEPSEN_TPU_MESH_WAIT_S", "7.5")
+    assert gates.get("JEPSEN_TPU_MESH_WAIT_S") == 7.5
 
 
 def test_str_choices_reject_unknown(monkeypatch):
@@ -196,14 +194,13 @@ def test_ec_marker_is_the_ssh_marker():
     assert control.SSHRemote._EC_MARK.startswith("__JEPSEN_TPU_EC")
 
 
-def test_probe_timeout_gate(monkeypatch):
+def test_platform_gate_selects_devices(monkeypatch):
     from jepsen_tpu import devices
-    monkeypatch.delenv("JEPSEN_TPU_PROBE_TIMEOUT", raising=False)
-    assert devices.probe_timeout() == 120.0
-    monkeypatch.setenv("JEPSEN_TPU_PROBE_TIMEOUT", "3.5")
-    assert devices.probe_timeout() == 3.5
-    monkeypatch.setenv("JEPSEN_TPU_PROBE_TIMEOUT", "eventually")
-    assert devices.probe_timeout() == 120.0   # malformed -> default
+    monkeypatch.setenv("JEPSEN_TPU_PLATFORM", "cpu")
+    assert {d.platform for d in devices.default_devices()} == {"cpu"}
+    monkeypatch.delenv("JEPSEN_TPU_PLATFORM")
+    import jax
+    assert devices.default_devices() == jax.devices()
 
 
 def test_trace_max_events_gate(monkeypatch):

@@ -131,3 +131,38 @@ class TestPipelineOverlap:
         assert len(info["parse_spans"]) == 6
         overlap = ingest.overlap_seconds(info["parse_spans"], dev_spans)
         assert overlap > 0.0, (info["parse_spans"], dev_spans)
+
+
+def _encode_in_worker(run_dir):
+    """Runs in a spawned pool worker: one streaming-pipeline encode,
+    then what JAX state the worker holds (read here, in the test only:
+    the program never reads private JAX state)."""
+    import os
+    import sys
+    _idx, payload, _einfo, _t0, _t1 = ingest._stream_worker(
+        (0, run_dir, "append", None, None))
+    backends = []
+    if "jax" in sys.modules:
+        from jax._src import xla_bridge
+        backends = sorted(xla_bridge._backends)
+    return (not isinstance(payload, Exception),
+            os.environ.get("JAX_PLATFORMS"), backends)
+
+
+class TestPoolWorkersLeaveTheChip:
+    def test_spawned_encode_initialises_no_backend(self, tmp_path,
+                                                   monkeypatch):
+        # the parent may hold the chip: a pool worker that initialised
+        # a backend would fight it for the device
+        import multiprocessing as mp
+        from concurrent.futures import ProcessPoolExecutor
+        (tmp_path / "s").mkdir()
+        dirs = synth.write_synth_store(tmp_path / "s", 1, 40, 4, 0)
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        with ProcessPoolExecutor(
+                max_workers=1, mp_context=mp.get_context("spawn")) as ex:
+            ok, platforms, backends = ex.submit(
+                _encode_in_worker, str(dirs[0])).result(timeout=300)
+        assert ok
+        assert platforms is None
+        assert backends == []

@@ -77,8 +77,10 @@ def assert_wr_identical(a, b):
 
 def shm_leaks() -> list[str]:
     try:
-        return [x for x in os.listdir("/dev/shm")
-                if x.startswith(shm.NAME_PREFIX)]
+        # this process's segments only: other test workers' pools
+        # create and reclaim their own concurrently
+        mine = f"{shm.NAME_PREFIX}_{os.getpid()}_"
+        return [x for x in os.listdir("/dev/shm") if x.startswith(mine)]
     except FileNotFoundError:   # non-Linux: nothing to scan
         return []
 

@@ -705,22 +705,26 @@ class TestRaceBackend:
         for h, r in zip(hists, res):
             assert r["valid?"] == knossos.analysis(CASR, h)["valid?"]
 
-    def test_race_survives_device_failure(self, monkeypatch):
-        # a device pipeline that raises must not take the race down:
-        # the CPU side's full set decides
+    def test_race_raises_on_device_failure(self, monkeypatch):
+        # a device pipeline that raises fails the race: the CPU engine
+        # never stands in for a broken device
+        import time
         from jepsen_tpu.checker import Linearizable
         monkeypatch.setenv("JEPSEN_TPU_BACKEND", "tpu")
         calls = []
         def boom(self, hists):
             calls.append(1)
             raise RuntimeError("boom")
+        orig_cpu = Linearizable._cpu
+        def slow_cpu(self, h, search_stats=None):
+            time.sleep(0.1)
+            return orig_cpu(self, h, search_stats=search_stats)
         monkeypatch.setattr(Linearizable, "_device_batch", boom)
-        hists = self._hists()
+        monkeypatch.setattr(Linearizable, "_cpu", slow_cpu)
         c = linearizable(CASR, backend="race")
-        res = c.check_batch({}, hists, {})
+        with pytest.raises(RuntimeError, match="boom"):
+            c.check_batch({}, self._hists(), {})
         assert calls, "race never entered the device side"
-        for h, r in zip(hists, res):
-            assert r["valid?"] == knossos.analysis(CASR, h)["valid?"]
 
     def test_race_via_env_from_cli_wiring(self, monkeypatch):
         # the CLI exports --backend race as JEPSEN_TPU_BACKEND=race and

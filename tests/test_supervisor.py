@@ -466,8 +466,10 @@ def test_keyboard_interrupt_is_never_quarantined(monkeypatch):
 
 def shm_leaks() -> list[str]:
     try:
-        return [x for x in os.listdir("/dev/shm")
-                if x.startswith(shm.NAME_PREFIX)]
+        # this process's segments only: other test workers' pools
+        # create and reclaim their own concurrently
+        mine = f"{shm.NAME_PREFIX}_{os.getpid()}_"
+        return [x for x in os.listdir("/dev/shm") if x.startswith(mine)]
     except OSError:
         return []
 

@@ -18,6 +18,7 @@ import json
 import os
 import sys
 import threading
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -74,7 +75,7 @@ def ctr(tr, name):
 def _aot_tmp(tmp_path, monkeypatch):
     """Every test gets its own executable-cache dir and a clean
     in-memory AOT map — no cross-test (or cross-run) executables."""
-    monkeypatch.setenv("JEPSEN_TPU_COMPILE_CACHE_DIR",
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
                        str(tmp_path / "aot-cache"))
     aot.clear_memory()
     yield
@@ -365,13 +366,42 @@ class TestDonation:
 # ---------------------------------------------------------------------------
 
 class TestAotCache:
+    def test_sharding_is_part_of_the_key(self, tmp_path):
+        """One cache dir, the same avals compiled once for 8-shard
+        inputs on the virtual mesh and once for one device: both run,
+        and neither is ever loaded for the other (the seed's 'Expected
+        args to execute_sharded_on_local_devices to have 8 shards')."""
+        import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        enc = synth.synth_encoded_history(64, K=8, inject_cycle=True)
+        shape = K.BatchShape.plan([enc] * 8)
+        packed = K.pack_batch([enc] * 8, shape)
+        fn = parallel.sharded_check_fn(None, shape)
+        devs = jax.devices()
+        assert len(devs) == 8
+        mesh = Mesh(np.asarray(devs), ("dp",))
+        single = parallel.shard_batch(None, packed)
+        sharded = tuple(jax.device_put(a, NamedSharding(mesh, P("dp")))
+                        for a in single)
+        kp = ("sharding-key-test",)
+        assert aot._fingerprint(fn, sharded, kp) \
+            != aot._fingerprint(fn, single, kp)
+        want = np.asarray(fn(*single))
+        assert all(w & (1 << K.G1C) for w in want)
+        for _ in range(2):          # cold, then from disk alone
+            for args in (sharded, single):
+                exe = aot.compiled_for(fn, args, kp)
+                assert np.asarray(exe(*args)).tolist() == want.tolist()
+            aot.clear_memory()
+        assert len(list(aot.cache_dir().glob("*.jtx"))) == 2
+
     def test_repeat_sweep_all_hits(self, tmp_path):
         dirs = append_dirs(tmp_path, n=4, T=30)
         encs = warm_encs(dirs)
         tr = trace.fresh_run("aot-cold")
         base = parallel.check_bucketed(encs)
         assert ctr(tr, "compile_cache_misses") >= 1
-        cache_files = list((tmp_path / "aot-cache").glob("*.jtx"))
+        cache_files = list((tmp_path / "aot-cache" / "executables").glob("*.jtx"))
         assert cache_files, "misses must persist executables to disk"
         # fresh in-memory state = a fresh process; only the disk layer
         # can answer now
@@ -389,13 +419,13 @@ class TestAotCache:
         parallel.check_bucketed(warm_encs(dirs))
         assert ctr(tr, "compile_cache_hits") == 0
         assert ctr(tr, "compile_cache_misses") == 0
-        assert not list((tmp_path / "aot-cache").glob("*.jtx"))
+        assert not list((tmp_path / "aot-cache" / "executables").glob("*.jtx"))
 
     def test_corrupt_entry_degrades_to_compile(self, tmp_path):
         dirs = append_dirs(tmp_path, n=3, T=30)
         encs = warm_encs(dirs)
         base = parallel.check_bucketed(encs)
-        for f in (tmp_path / "aot-cache").glob("*.jtx"):
+        for f in (tmp_path / "aot-cache" / "executables").glob("*.jtx"):
             f.write_bytes(b"not a pickled executable")
         aot.clear_memory()
         tr = trace.fresh_run("aot-corrupt")
@@ -403,12 +433,16 @@ class TestAotCache:
         assert got == base
         assert ctr(tr, "compile_cache_misses") >= 1
 
-    def test_cache_dir_gate(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("JEPSEN_TPU_COMPILE_CACHE_DIR",
+    def test_cache_dir_follows_jax_compilation_cache_dir(
+            self, tmp_path, monkeypatch):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
                            str(tmp_path / "elsewhere"))
-        assert aot.cache_dir() == tmp_path / "elsewhere"
-        monkeypatch.delenv("JEPSEN_TPU_COMPILE_CACHE_DIR")
-        assert aot.cache_dir().name == "executables"
+        assert aot.cache_root() == tmp_path / "elsewhere"
+        assert aot.cache_dir() == tmp_path / "elsewhere" / "executables"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = Path(__file__).resolve().parent.parent
+        assert aot.cache_root() == repo / ".jax_cache"
+        assert aot.cache_dir() == repo / ".jax_cache" / "executables"
 
 
 # ---------------------------------------------------------------------------
