@@ -9,7 +9,7 @@ TSAN_RT := $(shell gcc -print-file-name=libtsan.so)
 
 .PHONY: lint lint-json lint-changed env-table rule-table dur-table \
 	wire-table order-smoke \
-	crash-smoke test native native-sanitize bench bench-report \
+	crash-smoke test native native-sanitize bench \
 	bench-warm obs-smoke serve-smoke fleet-smoke trace-report \
 	cost-report \
 	search-report planner-report
@@ -144,12 +144,6 @@ native-sanitize:
 
 bench:
 	JAX_PLATFORMS=cpu $(PY) bench.py
-
-# The trajectory gate: trend table over the BENCH_*.json series in the
-# repo root, exit 1 when the latest round regresses past a declared
-# threshold vs its same-backend predecessor.
-bench-report:
-	$(PY) -m jepsen_tpu.cli bench-report
 
 # The copy-free warm-path gate: smoke-shape cold -> warm -> warm-again
 # sweeps (each its own process, shared store + executable cache); fails

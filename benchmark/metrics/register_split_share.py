@@ -1,0 +1,15 @@
+"""Share of a register pass the main thread spent relifting each run's
+history and splitting it per key: its `register_split` phase spans over
+the pass wall time."""
+
+from harness import spans
+
+
+def read(r):
+    p = r["pass"]
+    ev = p.get("events")
+    if not ev or not any(n == "register_split"
+                         for _t, _d, n in spans.main_thread_phases(ev)):
+        return None
+    return 100.0 * spans.main_thread_seconds(ev, "register_split") \
+        / p["wall_s"]

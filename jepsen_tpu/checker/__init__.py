@@ -736,40 +736,45 @@ class Linearizable(Checker):
         `stats_out`, each tier reports its own search telemetry
         (grid/frontier occupancy, rounds; the CPU oracle's WGL
         counters for fallbacks)."""
+        from .. import trace
         from .knossos import dense, kernels
         from .knossos import encode as kenc
+        tr = trace.get_current()
         with_stats = stats_out is not None
         stats: list = [None] * len(histories)
         dense_encs, dense_idx = [], []
         front_encs, front_idx = [], []
         cpu_idx = []
-        for i, hs in enumerate(histories):
-            try:
-                dense_encs.append(dense.encode_dense_history(hs))
-                dense_idx.append(i)
-            except kenc.EncodingError:
+        # the host build of the tier tensors: each history routed to
+        # the device tier it fits and encoded for it
+        with tr.phase_span("knossos_pack", keys=len(histories)):
+            for i, hs in enumerate(histories):
                 try:
-                    enc = kenc.encode_register_history(hs)
-                    # Feasibility gate: every simultaneously-open
-                    # write or unknown-value read doubles the frontier
-                    # (they apply in any order); open cas ops and
-                    # known-value reads prune on state mismatch —
-                    # empirically contributing about half a doubling
-                    # each. If the estimated closure can't fit the
-                    # arena, the kernel would burn a full device pass
-                    # only to report overflow (round 4's
-                    # tiers={"wgl": 8}); predictably-infeasible
-                    # histories go straight to the oracle. The
-                    # kernel's own overflow fallback still catches the
-                    # ones the estimate admits.
-                    budget = 2 * (max(self.frontier, 1).bit_length() - 1)
-                    if enc.half_doublings_peak > budget:
-                        cpu_idx.append(i)
-                    else:
-                        front_encs.append(enc)
-                        front_idx.append(i)
+                    dense_encs.append(dense.encode_dense_history(hs))
+                    dense_idx.append(i)
                 except kenc.EncodingError:
-                    cpu_idx.append(i)
+                    try:
+                        enc = kenc.encode_register_history(hs)
+                        # Feasibility gate: every simultaneously-open
+                        # write or unknown-value read doubles the frontier
+                        # (they apply in any order); open cas ops and
+                        # known-value reads prune on state mismatch —
+                        # empirically contributing about half a doubling
+                        # each. If the estimated closure can't fit the
+                        # arena, the kernel would burn a full device pass
+                        # only to report overflow (round 4's
+                        # tiers={"wgl": 8}); predictably-infeasible
+                        # histories go straight to the oracle. The
+                        # kernel's own overflow fallback still catches the
+                        # ones the estimate admits.
+                        budget = 2 * (max(self.frontier, 1).bit_length() - 1)
+                        if enc.half_doublings_peak > budget:
+                            cpu_idx.append(i)
+                        else:
+                            front_encs.append(enc)
+                            front_idx.append(i)
+                    except kenc.EncodingError:
+                        cpu_idx.append(i)
         results: list[dict | None] = [None] * len(histories)
         if dense_encs:
             ds: list | None = [] if with_stats else None
@@ -794,13 +799,14 @@ class Linearizable(Checker):
                     if fs is not None:
                         stats[i] = fs[j]
         if cpu_idx:
-            from .. import trace
-            trace.counter("register_cpu_routed").inc(len(cpu_idx))
-        for i in cpu_idx:
-            sd: dict | None = {} if with_stats else None
-            results[i] = self._cpu(histories[i], search_stats=sd)
-            if with_stats:
-                stats[i] = sd or None
+            tr.counter("register_cpu_routed").inc(len(cpu_idx))
+            with tr.phase_span("knossos_cpu", keys=len(cpu_idx)):
+                for i in cpu_idx:
+                    sd: dict | None = {} if with_stats else None
+                    results[i] = self._cpu(histories[i],
+                                           search_stats=sd)
+                    if with_stats:
+                        stats[i] = sd or None
         if with_stats:
             stats_out.extend(stats)
         return results  # type: ignore[return-value]

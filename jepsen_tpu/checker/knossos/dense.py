@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ... import trace
 from ...devices import default_devices
 from ...util import pad_to_multiple
 from .encode import CAS, READ, WRITE, EncodingError, _reduced_seq
@@ -324,30 +325,35 @@ def check_encoded_dense_batch(encs: list[DenseEncoded],
     out: list[dict | None] = [None] * len(encs)
     with_stats = stats_out is not None
     sout: list = [None] * len(encs)
+    tr = trace.get_current()
     for _slots, idxs in sorted(buckets.items()):
-        group = [encs[i] for i in idxs]
-        padded = pad_to_multiple(group, len(devices))
-        batch = pack_dense_batch(padded)
-        shape: DenseBatchShape = batch["shape"]
-        regs = jnp.asarray(batch["regs"])
-        comp = jnp.asarray(batch["comp"])
-        if len(devices) > 1:
-            mesh = jax.sharding.Mesh(np.asarray(devices), ("dp",))
-            sharding = jax.sharding.NamedSharding(
-                mesh, jax.sharding.PartitionSpec("dp"))
-            regs = jax.device_put(regs, sharding)
-            comp = jax.device_put(comp, sharding)
-        if with_stats:
-            valid, peak, rounds = check_dense_device(
-                regs, comp, n_values=shape.n_values,
-                n_slots=shape.n_slots, with_stats=True)
-            peak = np.asarray(peak)
-            rounds = np.asarray(rounds)
-        else:
-            valid = check_dense_device(
-                regs, comp, n_values=shape.n_values,
-                n_slots=shape.n_slots)
-        valid = np.asarray(valid)
+        with tr.phase_span("knossos_pack", keys=len(idxs)):
+            group = [encs[i] for i in idxs]
+            padded = pad_to_multiple(group, len(devices))
+            batch = pack_dense_batch(padded)
+            shape: DenseBatchShape = batch["shape"]
+            regs = jnp.asarray(batch["regs"])
+            comp = jnp.asarray(batch["comp"])
+            if len(devices) > 1:
+                mesh = jax.sharding.Mesh(np.asarray(devices), ("dp",))
+                sharding = jax.sharding.NamedSharding(
+                    mesh, jax.sharding.PartitionSpec("dp"))
+                regs = jax.device_put(regs, sharding)
+                comp = jax.device_put(comp, sharding)
+        # enqueue and block on the device: the main thread's wait
+        with tr.phase_span("knossos_wait", keys=len(idxs),
+                           S=shape.n_slots):
+            if with_stats:
+                valid, peak, rounds = check_dense_device(
+                    regs, comp, n_values=shape.n_values,
+                    n_slots=shape.n_slots, with_stats=True)
+                peak = np.asarray(peak)
+                rounds = np.asarray(rounds)
+            else:
+                valid = check_dense_device(
+                    regs, comp, n_values=shape.n_values,
+                    n_slots=shape.n_slots)
+            valid = np.asarray(valid)
         for j, i in enumerate(idxs):
             out[i] = {"valid?": bool(valid[j]), "analyzer": "tpu-dense",
                       "op-count": encs[i].n_ops}

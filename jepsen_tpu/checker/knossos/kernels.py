@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ... import trace
 from ...devices import default_devices
 from ...util import pad_to_multiple
 from .encode import (CAS, COMPLETE_EV, INVOKE_EV, READ, WRITE,
@@ -276,16 +277,18 @@ def check_encoded_batch(encs: list[EncodedRegisterHistory],
         return []
     n = len(encs)
     devices = devices if devices is not None else default_devices()
-    encs = pad_to_multiple(encs, len(devices))
-    batch = pack_register_batch(encs)
-    shape: RegisterBatchShape = batch["shape"]
-    events = jnp.asarray(batch["events"])
+    tr = trace.get_current()
+    with tr.phase_span("knossos_pack", keys=n):
+        encs = pad_to_multiple(encs, len(devices))
+        batch = pack_register_batch(encs)
+        shape: RegisterBatchShape = batch["shape"]
+        events = jnp.asarray(batch["events"])
 
-    if len(devices) > 1:
-        mesh = jax.sharding.Mesh(np.asarray(devices), ("dp",))
-        sharding = jax.sharding.NamedSharding(
-            mesh, jax.sharding.PartitionSpec("dp"))
-        events = jax.device_put(events, sharding)
+        if len(devices) > 1:
+            mesh = jax.sharding.Mesh(np.asarray(devices), ("dp",))
+            sharding = jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec("dp"))
+            events = jax.device_put(events, sharding)
 
     from .packed import packable
     fits = all(packable(e.n_values, shape.n_slots) for e in encs)
@@ -295,23 +298,25 @@ def check_encoded_batch(encs: list[EncodedRegisterHistory],
     # pinned by tests, so the downgrade is observability-only
     packed = (fits if packed is None else (packed and fits)) \
         and not with_stats
-    peak = rounds = explored = None
-    if packed:
-        from .packed import check_batch_device_packed
-        valid, overflow = check_batch_device_packed(
-            events, frontier=frontier, n_slots=shape.n_slots)
-    elif with_stats:
-        valid, overflow, peak, rounds, explored = check_batch_device(
-            events, frontier=frontier, n_slots=shape.n_slots,
-            with_stats=True)
-        peak = np.asarray(peak)
-        rounds = np.asarray(rounds)
-        explored = np.asarray(explored)
-    else:
-        valid, overflow = check_batch_device(
-            events, frontier=frontier, n_slots=shape.n_slots)
-    valid = np.asarray(valid)
-    overflow = np.asarray(overflow)
+    # enqueue and block on the device: the main thread's wait
+    with tr.phase_span("knossos_wait", keys=n, S=shape.n_slots):
+        peak = rounds = explored = None
+        if packed:
+            from .packed import check_batch_device_packed
+            valid, overflow = check_batch_device_packed(
+                events, frontier=frontier, n_slots=shape.n_slots)
+        elif with_stats:
+            valid, overflow, peak, rounds, explored = check_batch_device(
+                events, frontier=frontier, n_slots=shape.n_slots,
+                with_stats=True)
+            peak = np.asarray(peak)
+            rounds = np.asarray(rounds)
+            explored = np.asarray(explored)
+        else:
+            valid, overflow = check_batch_device(
+                events, frontier=frontier, n_slots=shape.n_slots)
+        valid = np.asarray(valid)
+        overflow = np.asarray(overflow)
     out = []
     for i, e in enumerate(encs[:n]):
         if overflow[i]:

@@ -278,14 +278,6 @@ def run_cli(test_fn: Callable[[dict, argparse.Namespace], dict],
              "hazards, concurrency, shm lifecycle, tracer discipline)")
     _lint.add_args(p_lint)
 
-    from .obs import bench_report as _breport   # stdlib-only
-    p_breport = sub.add_parser(
-        "bench-report",
-        help="bench-trajectory trend table + regression gate over the "
-             "BENCH_*.json series (exit 1 when the latest round "
-             "regresses past a declared threshold)")
-    _breport.add_args(p_breport)
-
     try:
         args = parser.parse_args(argv)
     except SystemExit as e:
@@ -295,9 +287,6 @@ def run_cli(test_fn: Callable[[dict, argparse.Namespace], dict],
         # no logging/backend/trace setup: lint parses source, it never
         # imports or executes the target package
         return _lint.run_from_args(args)
-    if args.command == "bench-report":
-        # same posture as lint: reads artifacts, never touches jax
-        return _breport.run_from_args(args)
 
     logging.basicConfig(
         level=logging.INFO,
@@ -468,8 +457,10 @@ def analyze_store(store: Store, checker: str = "append",
     from . import supervisor as sv
     from .obs import device as device_obs
     from .obs import search as search_obs
+    from . import jaxtrace
     from .store import VerdictJournal, analytics_path, costdb_path
     aot.configure_jax_cache()
+    jaxtrace.install()
     if report is None:
         report = gates.get("JEPSEN_TPU_REPORT")
     if mesh is None:
@@ -1064,13 +1055,10 @@ def _parse_timed(it):
     a "parse" phase span in the current tracer — analyze-store sweeps
     get the same parse/pack/h2d/dispatch/collect attribution as the
     bench's north-star loop."""
-    import time
-
     it = iter(it)
     while True:
-        t0 = time.perf_counter()
-        chunk = next(it, None)
-        trace.get_current().phase("parse", t0)
+        with trace.get_current().phase_span("parse"):
+            chunk = next(it, None)
         if chunk is None:
             return
         yield chunk
@@ -1219,28 +1207,35 @@ def _analyze_store_register(store: Store, run_dirs: list,
     #1. Runs whose client ops aren't register-shaped fall back to
     their own stored checker."""
     from . import independent, ingest
-    from .checker import linearizable, merge_valid, models
+    from .checker import linearizable, models
 
     # auto resolves to the device kernels when an accelerator is
     # reachable and honors the --backend env export either way
     c = linearizable(models.cas_register(), backend="auto")
+    tr = trace.get_current()
 
     subs: list[list] = []          # flattened subhistories
     owners: list[tuple[int, object]] = []   # (run index, key)
     fallback: list[int] = []
-    for i, (d, hist) in enumerate(
-            zip(run_dirs, ingest.parallel_load(run_dirs))):
+    with tr.phase_span("register_load", runs=len(run_dirs)):
+        hists = ingest.parallel_load(run_dirs)
+    for i, (d, hist) in enumerate(zip(run_dirs, hists)):
         if isinstance(hist, Exception):
             fallback.append(i)
             continue
-        hist = independent.relift_history(hist)
-        client_fs = {o.get("f") for o in hist
-                     if o.get("process") != "nemesis"
-                     and o.get("f") is not None}
-        if not client_fs or not client_fs <= {"read", "write", "cas"}:
+        with tr.phase_span("register_split") as split:
+            hist = independent.relift_history(hist)
+            client_fs = {o.get("f") for o in hist
+                         if o.get("process") != "nemesis"
+                         and o.get("f") is not None}
+            by_key = None
+            if client_fs and client_fs <= {"read", "write", "cas"}:
+                # one pass, all keys
+                by_key = independent.subhistories(hist)
+                split.note(keys=len(by_key))
+        if by_key is None:
             fallback.append(i)
             continue
-        by_key = independent.subhistories(hist)   # one pass, all keys
         ks = list(by_key)
         # a plain cas value is [old new] (scalars); a LIFTED cas value
         # is [key [old new]] — second element a list marks it lifted
@@ -1286,6 +1281,18 @@ def _analyze_store_register(store: Store, run_dirs: list,
             per_run_stats.setdefault(i, []).append(
                 (k, len(subs[j]), ksouts[j]))
 
+    with tr.phase_span("register_write", runs=len(run_dirs)):
+        return _write_register_verdicts(run_dirs, fallback, per_run,
+                                        per_run_stats, ksouts,
+                                        stored_check, journal)
+
+
+def _write_register_verdicts(run_dirs, fallback, per_run, per_run_stats,
+                             ksouts, stored_check, journal) -> int:
+    """The register sweep's per-run verdicts: results files, journal,
+    search records; returns the worst exit code."""
+    from .checker import merge_valid
+    from .obs import search as search_obs
     worst = 0
     for i, d in enumerate(run_dirs):
         if i in fallback:

@@ -31,10 +31,6 @@ This package is that layer, in four stdlib-only pieces:
     has a causal record even when trace.json was never written. Lint
     rule JT-TRACE-003 requires every event to go through
     `events.emit` with a declared kind — no ad-hoc dict writes.
-  * `bench_report` — the trajectory gate: `python -m jepsen_tpu.cli
-    bench-report` loads the `BENCH_*.json` series, prints a per-metric
-    trend table, and exits non-zero when the latest round regresses
-    past a declared threshold vs its same-backend predecessor.
   * `attribution` — the critical-path report over the MERGED sweep
     timeline (parent phases + per-worker spool tracks + device
     windows): serial bottleneck decomposition, device-gap stall

@@ -30,8 +30,7 @@ sweep:
 
 Exposed as `analyze-store --report` -> `<store>/report.json` +
 human-readable `report.md`; bench.py embeds the same decomposition in
-the north_star and cache_warm blocks and `bench-report` trends the
-shares. Stdlib-only; events come in as plain dicts, so this runs on
+the north_star and cache_warm blocks. Stdlib-only; events come in as plain dicts, so this runs on
 an archived trace.json as well as a live tracer.
 """
 
@@ -425,8 +424,8 @@ def write_report(store_base, events: list, metrics: dict | None = None,
     journal discipline) and return their paths. With
     `per_shard_events` ({shard: event list} — a mesh sweep's
     coordinator merge) the report additionally carries `per_shard`:
-    each shard's own stage-share decomposition, so `bench-report` and
-    operators can pin per-shard ceilings, not just fleet-wide ones.
+    each shard's own stage-share decomposition, so operators can pin
+    per-shard ceilings, not just fleet-wide ones.
     With `device_records` (the cost observatory's finalized records —
     merged across shards by the coordinator) it carries the `device`
     roofline section: per-(executable, geometry) achieved-vs-peak

@@ -488,7 +488,7 @@ def flush(path, store_base=None) -> int:
 def bandwidth_share(recs: list[dict]) -> dict | None:
     """The sweep-level achieved-bandwidth share: total bytes accessed
     over total measured device seconds, against the peak HBM bandwidth
-    the records resolved — the single number bench-report trends.
+    the records resolved — the sweep's one bandwidth number.
     None when no record carries both a cost analysis and windows."""
     bytes_total = 0.0
     secs_total = 0.0

@@ -185,6 +185,11 @@ def _sharded_check_fn_cached(mesh: Mesh | None, shape: K.BatchShape,
         classify=classify, realtime=realtime, process_order=process_order,
         constrain=constrain, use_pallas=use_pallas, use_int8=use_int8,
         fused=fused, with_stats=with_stats)
+    # JAX names the compiled module after the function it traces, and a
+    # bare partial reads as `<unknown>` (`jit__unknown` in a device
+    # trace, cached and reloaded under that name). The executable's
+    # name is the kernel's; the AOT cache key is not changed by it.
+    f.__name__ = K.check_batched_impl.__name__
     if mesh is None:
         if donate:
             # donated inputs: XLA reuses the six packed tensors' HBM
@@ -373,6 +378,12 @@ def _acc_phase(phases: dict | None, key: str, t0: float) -> None:
         phases[key] = phases.get(key, 0.0) + dt
 
 
+def _phase(phases: dict | None, key: str, **args):
+    """`with _phase(phases, "pack"):` — `_acc_phase` as a context
+    manager, which also opens the phase's profiler annotation."""
+    return trace.get_current().phase_span(key, phases, **args)
+
+
 class PendingVerdicts:
     """Verdicts still in flight: `check_bucketed_async` queues every
     bucket's device dispatch without a host sync, so the caller can
@@ -424,7 +435,12 @@ class PendingVerdicts:
         # instead of returning all-Nones and double-counting.
         if self._result is not None:
             return self._result
-        t0 = time.perf_counter()
+        with _phase(phases, "collect"):
+            out = self._resolve()
+        self._result = out
+        return out  # type: ignore[return-value]
+
+    def _resolve(self) -> list:
         tr = trace.get_current()
         out: list[dict | None] = [None] * self._n
         for idx, flags, t_disp, donated, smeta in self._parts:
@@ -452,9 +468,7 @@ class PendingVerdicts:
                           else K.flags_to_names(int(w)))
         self._parts = []
         tr.gauge("inflight_depth").set(0)   # fully drained
-        _acc_phase(phases, "collect", t0)
-        self._result = out
-        return out  # type: ignore[return-value]
+        return out
 
 
 def pack_thread_enabled() -> bool:
@@ -492,56 +506,54 @@ def _prep_bucket(encs: Sequence, bucket: list[int], mesh: Mesh | None,
     before, and the bytes it copied for WARM (cache-loaded) histories
     are attributed to `warm_copy_bytes` — the number the warm
     north-star bench drives to zero."""
-    t0 = time.perf_counter()
-    group = [encs[i] for i in bucket]
-    bucket_mesh = mesh
-    if mesh is not None:
-        # Pad ragged buckets to a dp multiple by replicating the
-        # last history (results dropped at collect) so the dispatch
-        # still shards across the mesh instead of falling to one
-        # device — unless the padding itself would blow the budget
-        # (a single history bigger than budget/dp), in which case
-        # dispatch unsharded rather than 8x over budget.
-        tpad = max(K.pad_to(max(e.n for e in group), 128), 1)
-        padded = pad_to_multiple(group, dp)
-        if len(padded) * tpad * tpad <= budget_cells:
-            group = padded
-        else:
-            bucket_mesh = None
-    shape = K.BatchShape.plan(group)
-    packed = K.pack_batch_views(group, shape) \
-        if bucket_mesh is None else None
-    if packed is None:
-        packed = K.pack_batch(group, shape)
+    with _phase(phases, "pack"):
+        group = [encs[i] for i in bucket]
+        bucket_mesh = mesh
+        if mesh is not None:
+            # Pad ragged buckets to a dp multiple by replicating the
+            # last history (results dropped at collect) so the dispatch
+            # still shards across the mesh instead of falling to one
+            # device — unless the padding itself would blow the budget
+            # (a single history bigger than budget/dp), in which case
+            # dispatch unsharded rather than 8x over budget.
+            tpad = max(K.pad_to(max(e.n for e in group), 128), 1)
+            padded = pad_to_multiple(group, dp)
+            if len(padded) * tpad * tpad <= budget_cells:
+                group = padded
+            else:
+                bucket_mesh = None
+        shape = K.BatchShape.plan(group)
+        packed = K.pack_batch_views(group, shape) \
+            if bucket_mesh is None else None
+        if packed is None:
+            packed = K.pack_batch(group, shape)
+            if tr.enabled:
+                warm = sum(
+                    e.appends.nbytes + e.reads.nbytes
+                    + e.invoke_index.nbytes + e.complete_index.nbytes
+                    + e.process.nbytes
+                    for e in group if getattr(e, "warm", False))
+                if warm:
+                    tr.counter("warm_copy_bytes").inc(warm)
         if tr.enabled:
-            warm = sum(
-                e.appends.nbytes + e.reads.nbytes
-                + e.invoke_index.nbytes + e.complete_index.nbytes
-                + e.process.nbytes
-                for e in group if getattr(e, "warm", False))
-            if warm:
-                tr.counter("warm_copy_bytes").inc(warm)
-    if tr.enabled:
-        # padding waste this dispatch pays: B_pad·T_pad² minus the
-        # ORIGINAL bucket's own cells, so dp-replica padding (group
-        # may hold replicated histories) counts as waste too
-        cells = len(group) * shape.n_txns * shape.n_txns
-        tr.counter("pad_waste_cells").inc(
-            cells - sum(max(_size_of(encs[i]), 1) ** 2 for i in bucket))
-        # per-dispatch device-resident footprint, in closure cells —
-        # the HBM-envelope invariant (max over dispatches x
-        # max_inflight <= budget_cells) is asserted against this
-        tr.histogram("bucket_cells").observe(cells)
-    _acc_phase(phases, "pack", t0)
+            # padding waste this dispatch pays: B_pad·T_pad² minus the
+            # ORIGINAL bucket's own cells, so dp-replica padding (group
+            # may hold replicated histories) counts as waste too
+            cells = len(group) * shape.n_txns * shape.n_txns
+            tr.counter("pad_waste_cells").inc(
+                cells - sum(max(_size_of(encs[i]), 1) ** 2 for i in bucket))
+            # per-dispatch device-resident footprint, in closure cells —
+            # the HBM-envelope invariant (max over dispatches x
+            # max_inflight <= budget_cells) is asserted against this
+            tr.histogram("bucket_cells").observe(cells)
     return bucket, bucket_mesh, shape, packed
 
 
 def _h2d_bucket(item: tuple, phases: dict | None) -> tuple:
     """device_put / sharding of one packed bucket (h2d phase)."""
     bucket, bucket_mesh, shape, packed = item
-    t0 = time.perf_counter()
-    args = shard_batch(bucket_mesh, packed)
-    _acc_phase(phases, "h2d", t0)
+    with _phase(phases, "h2d"):
+        args = shard_batch(bucket_mesh, packed)
     return bucket, bucket_mesh, shape, args
 
 
@@ -809,26 +821,28 @@ def check_bucketed_async(encs: Sequence, mesh: Mesh | None = None, *,
     kw = dict(classify=classify, realtime=realtime,
               process_order=process_order, fused=fused,
               with_stats=bool(with_stats))
-    t0 = time.perf_counter()
     eff_budget = max(1, budget_cells // depth)
-    pl = _planner.get()
-    if pl is not None:
-        # the cost-aware planner races candidate pad multiples on
-        # predicted device seconds and keeps the winner's composition;
-        # it answers bucket_by_length's exact output (multiple 128)
-        # whenever it has no model — and composition only moves
-        # histories between dispatches, never changes a verdict
-        buckets = pl.plan_buckets(encs, budget_cells=eff_budget, dp=dp)
-    else:
-        buckets = bucket_by_length(encs, budget_cells=eff_budget, dp=dp)
-    # Singleton buckets whose one history alone exceeds the per-slot
-    # budget cannot honor depth-sharing: peel them off to dispatch
-    # strictly alone after the pipelined buckets drain.
-    oversized = [b for b in buckets
-                 if _est_cells(encs, b, dp) > eff_budget]
-    buckets = [b for b in buckets
-               if _est_cells(encs, b, dp) <= eff_budget]
-    _acc_phase(phases, "pack", t0)
+    with _phase(phases, "pack"):
+        pl = _planner.get()
+        if pl is not None:
+            # the cost-aware planner races candidate pad multiples on
+            # predicted device seconds and keeps the winner's
+            # composition; it answers bucket_by_length's exact output
+            # (multiple 128) whenever it has no model — and composition
+            # only moves histories between dispatches, never changes a
+            # verdict
+            buckets = pl.plan_buckets(encs, budget_cells=eff_budget,
+                                      dp=dp)
+        else:
+            buckets = bucket_by_length(encs, budget_cells=eff_budget,
+                                       dp=dp)
+        # Singleton buckets whose one history alone exceeds the
+        # per-slot budget cannot honor depth-sharing: peel them off to
+        # dispatch strictly alone after the pipelined buckets drain.
+        oversized = [b for b in buckets
+                     if _est_cells(encs, b, dp) > eff_budget]
+        buckets = [b for b in buckets
+                   if _est_cells(encs, b, dp) <= eff_budget]
 
     def finish(idx, flags, t_disp=None, donated=False, smeta=None):
         out = _finish_part(encs, idx, flags, mesh, eff_budget, kw,
@@ -841,49 +855,56 @@ def check_bucketed_async(encs: Sequence, mesh: Mesh | None = None, *,
 
     def resolve_oldest():
         j = inflight.pop(0)
-        t0 = time.perf_counter()
-        idx, flags, t_disp, donated, smeta = parts[j]
-        parts[j] = (idx, finish(idx, flags, t_disp, donated, smeta),
-                    None, False, None)
-        tr.gauge("inflight_depth").set(len(inflight))
-        _acc_phase(phases, "collect", t0)
+        with _phase(phases, "collect"):
+            idx, flags, t_disp, donated, smeta = parts[j]
+            parts[j] = (idx, finish(idx, flags, t_disp, donated, smeta),
+                        None, False, None)
+            tr.gauge("inflight_depth").set(len(inflight))
 
     def dispatch(item) -> bool:
         """Enqueue one packed bucket async; returns False when the
         bucket was instead resolved synchronously (an OOM at enqueue
         went down the backdown path — nothing joined the pipeline)."""
         bucket, bucket_mesh, shape, args = item
-        t0 = time.perf_counter()
-        donate = _donate_active(bucket_mesh)
-        fn = _dispatch_fn(bucket_mesh, shape, kw, args, donate)
+        # the bucket geometry rides both sub-spans: batch x padded txns
+        geo = {"B": int(args[0].shape[0]), "T": int(shape.n_txns)}
         try:
-            sv.maybe_inject_oom()
-            out = fn(*args)
-            # a kernel-stats dispatch returns (flags, stats); the
-            # flags array stays the dispatch's identity (device
-            # windows, cost observatory) and the stats ride as smeta
-            flags, dev_stats = out if isinstance(out, tuple) \
-                else (out, None)
-            if donate:
-                _note_donation(tr, args)
-            parts.append((bucket, flags, time.perf_counter(), donate,
-                          (dev_stats, shape) if dev_stats is not None
-                          else None))
-            obs_device.begin_dispatch(flags, kw, shape,
-                                      bucket_mesh is None, donate,
-                                      args, tr)
+            with _phase(phases, "dispatch"):
+                # dispatch.resolve / dispatch.enqueue split the phase
+                # for the trace only: `phase` spans on this thread
+                # (they name its idle gaps) that add to no total
+                with tr.span("dispatch.resolve", cat="phase", **geo):
+                    donate = _donate_active(bucket_mesh)
+                    fn = _dispatch_fn(bucket_mesh, shape, kw, args,
+                                      donate)
+                with tr.span("dispatch.enqueue", cat="phase", **geo):
+                    sv.maybe_inject_oom()
+                    out = fn(*args)
+                    # a kernel-stats dispatch returns (flags, stats);
+                    # the flags array stays the dispatch's identity
+                    # (device windows, cost observatory) and the stats
+                    # ride as smeta
+                    flags, dev_stats = out if isinstance(out, tuple) \
+                        else (out, None)
+                    if donate:
+                        _note_donation(tr, args)
+                    parts.append((bucket, flags, time.perf_counter(),
+                                  donate,
+                                  (dev_stats, shape)
+                                  if dev_stats is not None else None))
+                    obs_device.begin_dispatch(flags, kw, shape,
+                                              bucket_mesh is None,
+                                              donate, args, tr)
+                    inflight.append(len(parts) - 1)
+                    tr.counter("buckets_dispatched").inc()
+                    tr.gauge("inflight_depth").set(len(inflight))
         except BaseException as e:
             if not sv.is_oom_error(e) or sv.strict_enabled():
                 raise
-            _acc_phase(phases, "dispatch", t0)
             parts.append((bucket, _oom_backdown(
                 encs, bucket, mesh, eff_budget, kw, tr, phases, e),
                 None, False, None))
             return False
-        inflight.append(len(parts) - 1)
-        tr.counter("buckets_dispatched").inc()
-        tr.gauge("inflight_depth").set(len(inflight))
-        _acc_phase(phases, "dispatch", t0)
         return True
 
     def handle_failed(bucket, e):
@@ -952,9 +973,8 @@ def check_bucketed_async(encs: Sequence, mesh: Mesh | None = None, *,
                 # ("feed"): with pack/h2d accruing on their own thread,
                 # the main thread's wall clock partitions into
                 # feed/dispatch/collect instead
-                t0 = time.perf_counter()
-                item = out.get()
-                _acc_phase(phases, "feed", t0)
+                with _phase(phases, "feed"):
+                    item = out.get()
                 if item is _DONE:
                     break
                 if isinstance(item, BaseException):

@@ -211,14 +211,18 @@ class VerdictDaemon:
         self._threads: list[threading.Thread] = []
         self._sched_thread: threading.Thread | None = None
         self._stopped = False
+        # fold ids, for the spans of a fold's requests (the dispatch
+        # thread alone takes them)
+        self._fold_seq = 0
 
     # -- lifecycle ---------------------------------------------------------
 
     def start(self) -> "VerdictDaemon":
         from .. import shm as _shm
         from ..parallel import folding
-        from .. import aot
+        from .. import aot, jaxtrace
         aot.configure_jax_cache()
+        jaxtrace.install()
         base = Path(self.store.base)
         base.mkdir(parents=True, exist_ok=True)
         trace.fresh_run(f"serve:{base.name}", scope="sweep")
@@ -593,7 +597,7 @@ class VerdictDaemon:
             return
         from .. import planner as planner_mod
         from ..parallel import folding
-        enc = self._resolve_payload(frame, checker)
+        enc = self._resolve_payload(frame, checker, rid, conn.tenant)
         n_txns = int(getattr(enc, "n", 1) or 1)
         pl = planner_mod.get()
         # admission price: the planner's model-predicted device
@@ -636,21 +640,25 @@ class VerdictDaemon:
                    "delay_s": self.admission.retry_after_s(),
                    "queue_depth": depth})
 
-    def _resolve_payload(self, frame: dict, checker: str):
+    def _resolve_payload(self, frame: dict, checker: str,
+                         rid: str | None = None, tenant: str | None = None):
         """CHECK frame -> encoding (or the Exception, which the fold
         quarantines at the `encode` stage — a tenant's bad history
-        costs the tenant an `unknown` verdict, never the daemon)."""
+        costs the tenant an `unknown` verdict, never the daemon). The
+        `serve_encode` span carries the request's id and tenant, as
+        its later spans do."""
+        who = {"id": rid, "tenant": tenant}
         try:
             if frame.get("dir"):
                 from .. import ingest
-                with trace.span("serve_encode", kind="dir"):
+                with trace.span("serve_encode", kind="dir", **who):
                     return ingest.encode_run_dir(frame["dir"], checker)
             if frame.get("shm"):
                 from .. import shm
-                with trace.span("serve_encode", kind="shm"):
+                with trace.span("serve_encode", kind="shm", **who):
                     return shm.materialize(frame["shm"])
             if frame.get("history") is not None:
-                with trace.span("serve_encode", kind="inline"):
+                with trace.span("serve_encode", kind="inline", **who):
                     if checker == "append":
                         from ..checker.elle.encode import (
                             encode_history, lean_anomalies)
@@ -709,9 +717,17 @@ class VerdictDaemon:
         # kernel.* metrics only (the daemon is long-lived; the
         # per-sweep ledger is analyze-store's)
         souts: list | None = [] if obs_search.enabled() else None
+        self._fold_seq += 1
+        fold = self._fold_seq
+        t_fold = time.perf_counter()
+        for r in picked:
+            # admitted (encoded and queued) until its fold starts
+            tr.add_span("serve_admission_wait", r.t0, t_fold,
+                        track="serve", id=r.rid, fold=fold)
         with tr.span("serve_fold", checker=checker,
                      histories=len(picked),
-                     tenants=len(by_tenant)):
+                     tenants=len(by_tenant), fold=fold,
+                     ids=[r.rid for r in picked]):
             # the stats kwarg is passed only when requested, so
             # stats-free dispatcher doubles (test seams) keep working
             if souts is not None:
@@ -744,6 +760,8 @@ class VerdictDaemon:
                 and k < len(souts) else None
             if stats is not None:
                 obs_search.note_metrics(stats, tr)
+            # serve_reply: journal append, metrics and the verdict frame
+            t_reply = time.perf_counter()
             res = _json_safe(res)
             ent = self._tenant_state(r.tenant)
             with self._jlock:
@@ -782,6 +800,8 @@ class VerdictDaemon:
                 if not journaled:
                     frame["journaled"] = False
                 r.conn.send(frame)
+            tr.add_span("serve_reply", t_reply, time.perf_counter(),
+                        id=r.rid, fold=fold)
         for t in by_tenant:
             slug = store_mod.safe_tenant(t)
             tr.gauge(f"serve.{slug}.queue_depth").set(

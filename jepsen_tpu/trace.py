@@ -37,6 +37,17 @@ costs well under a microsecond — the dp8-efficiency floor is
 unaffected. The module imports nothing but the stdlib (plus the
 stdlib-only `gates` registry); `jax` is touched only inside an
 explicitly enabled profiler session.
+
+On the profiler's clock: every tracer records its origin in
+CLOCK_REALTIME nanoseconds (`origin_realtime_ns`, taken back to back
+with its perf_counter origin) — the clock `time.time_ns()`, JAX's
+compile events and the profiler's host events share — in trace.json's
+process metadata, metrics.json and the worker spool meta line, so any
+reader can place a span on a device trace without an anchor. And once
+a JAX-importing entry point installs an annotation factory
+(`set_annotation`, done by `jaxtrace.install`), every span and phase
+of an enabled tracer also opens a profiler annotation
+`program:<name>` on its own thread, beside the device ops.
 """
 
 from __future__ import annotations
@@ -66,6 +77,7 @@ DECLARED_METRICS: dict[str, frozenset] = {
         "compile_cache_hits", "compile_cache_misses", "cost_records",
         "donated_bytes", "fleet_failovers", "fleet_fences",
         "fleet_replayed_verdicts", "fleet_spills", "h2d_bytes",
+        "jit_compiles", "jit_lowerings", "jit_traces",
         "kernel.cyclic_histories", "kernel.stats_records",
         "native_fallback", "oom_retries", "pad_waste_cells",
         "planner.cold_starts", "planner.decisions",
@@ -212,8 +224,30 @@ _NULL_METRIC = _NullMetric()
 # Span context managers
 # ---------------------------------------------------------------------------
 
+#: The profiler-annotation factory: name -> a context manager opened
+#: around every span and phase of an enabled tracer (None until a
+#: JAX-importing entry point installs one; this module never imports
+#: jax to make it).
+_annotation = None
+
+
+def set_annotation(factory) -> None:
+    """Install (or, with None, remove) the annotation factory."""
+    global _annotation
+    _annotation = factory
+
+
+def _open_annotation(name: str):
+    ann = _annotation
+    if ann is None:
+        return None
+    a = ann("program:" + name)
+    a.__enter__()
+    return a
+
+
 class _SpanCM:
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args):
         self._tracer = tracer
@@ -222,12 +256,50 @@ class _SpanCM:
         self._args = args
 
     def __enter__(self):
+        self._ann = _open_annotation(self._name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self._tracer._complete(self._name, self._t0, time.perf_counter(),
                                self._cat, self._args)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        return False
+
+
+class _PhaseCM:
+    """`with tracer.phase_span(key, phases)`: one phase, recorded as
+    `phase(key, t0)` records it (span, `phase_totals`, histogram), its
+    duration added to the caller's `phases` dict, and — on an enabled
+    tracer — a profiler annotation open for its whole length."""
+
+    __slots__ = ("_tracer", "_key", "_phases", "_args", "_t0", "_ann")
+
+    def __init__(self, tracer, key: str, phases, args):
+        self._tracer = tracer
+        self._key = key
+        self._phases = phases
+        self._args = args
+
+    def __enter__(self):
+        self._ann = _open_annotation(self._key) \
+            if self._tracer.enabled else None
+        self._t0 = time.perf_counter()
+        return self
+
+    def note(self, **args) -> None:
+        """Add args learned inside the phase (a count, say)."""
+        self._args = {**(self._args or {}), **args}
+
+    def __exit__(self, *exc):
+        dt = self._tracer._phase_done(self._key, self._t0,
+                                      time.perf_counter(), self._args)
+        if self._phases is not None:
+            self._phases[self._key] = self._phases.get(self._key, 0.0) \
+                + dt
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         return False
 
 
@@ -236,6 +308,9 @@ class _NullCM:
 
     def __enter__(self):
         return self
+
+    def note(self, **args) -> None:
+        pass
 
     def __exit__(self, *exc):
         return False
@@ -261,6 +336,7 @@ class NullTracer:
     trace_id = None
     spool_dir = None
     pid = None
+    origin_realtime_ns = None
 
     def span(self, name: str, **args):
         return _NULL_CM
@@ -271,10 +347,18 @@ class NullTracer:
     def phase(self, key: str, t0: float) -> float:
         return time.perf_counter() - t0
 
+    def _phase_done(self, key, t0, t1, args) -> float:
+        return t1 - t0
+
+    def phase_span(self, key: str, phases: dict | None = None, **args):
+        return _NULL_CM if phases is None \
+            else _PhaseCM(self, key, phases, None)
+
     def device_complete(self, name, t0, t1=None, **args):
         pass
 
-    def add_span(self, name, t0, t1, track=None, clock="perf", **args):
+    def add_span(self, name, t0, t1, track=None, clock="perf",
+                 cat="span", **args):
         pass
 
     def instant(self, name, track=None, **args):
@@ -345,6 +429,9 @@ class Tracer:
         self._max_events = max_events
         self._dropped = 0
         self._origin = time.perf_counter()
+        # the same instant on CLOCK_REALTIME (ns), read back to back:
+        # the profiler's host clock and JAX's compile events use it
+        self.origin_realtime_ns = time.time_ns()
         # CLOCK_MONOTONIC -> perf_counter offset, for external spans
         # measured with time.monotonic (ingest pool workers)
         self._mono_off = time.perf_counter() - time.monotonic()
@@ -391,10 +478,20 @@ class Tracer:
     def phase(self, key: str, t0: float) -> float:
         """Record a completed phase span started at perf_counter() time
         `t0`, accumulate its per-phase total + histogram, and return
-        the duration — the adapter `parallel._acc_phase` rides."""
-        t1 = time.perf_counter()
+        the duration — the adapter `parallel._acc_phase` rides. A phase
+        recorded after the fact carries no profiler annotation;
+        `phase_span` does."""
+        return self._phase_done(key, t0, time.perf_counter(), None)
+
+    def phase_span(self, key: str, phases: dict | None = None, **args):
+        """`with tracer.phase_span("pack", phases, B=2):` — the
+        context-manager form of `phase`, which also opens the profiler
+        annotation and adds the duration to `phases` when given."""
+        return _PhaseCM(self, key, phases, args or None)
+
+    def _phase_done(self, key: str, t0: float, t1: float, args) -> float:
         dt = t1 - t0
-        self._complete(key, t0, t1, "phase", None)
+        self._complete(key, t0, t1, "phase", args)
         with _MLOCK:
             self._phase_totals[key] = self._phase_totals.get(key, 0.0) + dt
         self.histogram(f"phase.{key}").observe(dt)
@@ -420,22 +517,28 @@ class Tracer:
 
     def add_span(self, name: str, t0: float, t1: float,
                  track: str | None = None, clock: str = "perf",
-                 **args) -> None:
+                 cat: str = "span", **args) -> None:
         """Record an externally measured span — e.g. an ingest pool
         worker's parse window, taken with time.monotonic in another
-        process (`clock="monotonic"` converts)."""
+        process (`clock="monotonic"` converts), or a JAX compile step
+        stamped with time.time() (`clock="realtime"`). Without a
+        `track` it lands on the calling thread's."""
         if clock == "monotonic":
             t0 += self._mono_off
             t1 += self._mono_off
+        elif clock == "realtime":
+            off = self._origin - self.origin_realtime_ns / 1e9
+            t0 += off
+            t1 += off
         if track is None:
-            self._complete(name, t0, t1, "span", args or None)
+            self._complete(name, t0, t1, cat, args or None)
             return
         if not self._room():
             return
         ts = (t0 - self._origin) * 1e6
         dur = max(0.0, (t1 - t0) * 1e6)
         self._events.append({
-            "name": name, "cat": "span", "ph": "X",
+            "name": name, "cat": cat, "ph": "X",
             "tid": self._laned_tid(track, ts, ts + dur),
             "ts": ts, "dur": dur,
             **({"args": args} if args else {})})
@@ -537,7 +640,10 @@ class Tracer:
         pid = self.pid
         ev: list[dict] = [{
             "name": "process_name", "ph": "M", "pid": pid, "tid": 0,
-            "args": {"name": self.run or "jepsen-tpu"}}]
+            "args": {"name": self.run or "jepsen-tpu",
+                     # ts 0 on CLOCK_REALTIME: ts_us * 1e3 + this is a
+                     # span's start on the profiler's host clock
+                     "origin_realtime_ns": self.origin_realtime_ns}}]
         for tid, tname in sorted(self._threads.items()):
             ev.append({"name": "thread_name", "ph": "M", "pid": pid,
                        "tid": tid, "args": {"name": tname}})
@@ -578,6 +684,7 @@ class Tracer:
                 "phase_totals_secs": {k: round(v, 6) for k, v in
                                       sorted(self._phase_totals.items())},
                 "dropped_events": self._dropped,
+                "origin_realtime_ns": self.origin_realtime_ns,
             }
 
     def export_metrics(self, path) -> Path:
@@ -797,7 +904,11 @@ def ensure_worker_tracer(tctx: dict | None) -> None:
             # queue latency; on a shared CLOCK_MONOTONIC (Linux) the
             # alignment error is zero and this is pure diagnostics
             "t_send": tctx.get("t_send"),
-            "t_recv": time.monotonic()}) + "\n")
+            "t_recv": time.monotonic(),
+            # the worker tracer's origin on CLOCK_REALTIME, as the
+            # parent's trace.json carries its own
+            "origin_realtime_ns": tr.origin_realtime_ns,
+            "origin_mono": tr.origin_mono()}) + "\n")
         f.flush()
     except OSError:
         log.debug("worker spool open failed", exc_info=True)
@@ -1105,6 +1216,9 @@ class jax_profile_session:
         if jax_profile_enabled():
             try:
                 import jax
+                from . import jaxtrace
+                # the program's spans ride the capture as annotations
+                jaxtrace.install()
                 self.out_dir.mkdir(parents=True, exist_ok=True)
                 jax.profiler.start_trace(str(self.out_dir))
                 self._active = True

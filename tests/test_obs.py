@@ -3,8 +3,7 @@ write atomicity under a concurrent reader, the Prometheus exposition
 (golden-file), the `/metrics`+`/healthz` endpoint and its gates, the
 typed flight-recorder event API (including a fault-injected sweep
 whose every quarantine lands in events.jsonl), crash-atomic
-trace/metrics export, and the bench-trajectory regression gate's exit
-codes. All tier-1, CPU-only.
+trace/metrics export. All tier-1, CPU-only.
 """
 
 from __future__ import annotations
@@ -20,7 +19,6 @@ import pytest
 
 from jepsen_tpu import obs, supervisor, trace
 from jepsen_tpu.checker.elle.synth import synth_append_history
-from jepsen_tpu.obs import bench_report
 from jepsen_tpu.obs.health import (HealthSampler, health_snapshot,
                                    maybe_start_health_sampler)
 from jepsen_tpu.obs.prom import (MetricsServer,
@@ -524,120 +522,3 @@ def test_trace_export_failure_leaves_previous_artifact(tmp_path,
     monkeypatch.setattr(os, "replace", real_replace)
     assert p.read_text() == before      # old artifact intact
     assert not list(tmp_path.glob(".metrics.json.*"))
-
-
-# ---------------------------------------------------------------------------
-# bench-report: the trajectory regression gate
-# ---------------------------------------------------------------------------
-
-def _round(path, parsed):
-    Path(path).write_text(json.dumps({"n": 1, "parsed": parsed}))
-    return Path(path)
-
-
-def test_bench_report_clean_series(tmp_path, capsys):
-    """A steady two-round series found by the default glob prints the
-    trend table and exits 0."""
-    for n, v in ((1, 100.0), (2, 101.0)):
-        _round(tmp_path / f"BENCH_r0{n}.json",
-               {"backend": "cpu", "value": v,
-                "north_star": {"value": v / 2, "sweep_secs": 1.0}})
-    rc = bench_report.report(bench_report.default_artifacts(tmp_path))
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "north-star hist/s" in out and "REGRESSED" not in out
-
-
-def test_bench_report_flags_synthetic_regression(tmp_path, capsys):
-    a = _round(tmp_path / "BENCH_r01.json",
-               {"backend": "cpu", "value": 100.0,
-                "north_star": {"value": 50.0, "sweep_secs": 1.0}})
-    b = _round(tmp_path / "BENCH_r02.json",
-               {"backend": "cpu", "value": 10.0,     # −90%: regression
-                "north_star": {"value": 49.0, "sweep_secs": 1.1}})
-    rc = bench_report.report([a, b])
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert "REGRESSED" in out and "elle-append hist/s" in out
-    # the within-tolerance north-star drift is NOT flagged
-    assert out.count("REGRESSED") == 1
-
-
-def test_bench_report_lower_is_better_and_zero_tolerance(tmp_path,
-                                                         capsys):
-    a = _round(tmp_path / "BENCH_r01.json",
-               {"backend": "cpu", "north_star": {"sweep_secs": 1.0},
-                "lint": {"findings_open": 0}})
-    b = _round(tmp_path / "BENCH_r02.json",
-               {"backend": "cpu", "north_star": {"sweep_secs": 2.0},
-                "lint": {"findings_open": 1}})
-    rc = bench_report.report([a, b])
-    out = capsys.readouterr().out
-    assert rc == 1
-    # sweep wall time +100% and any lint-findings increase both flag
-    assert out.count("REGRESSED") == 2
-
-
-def test_bench_report_mesh_efficiency_floor(tmp_path, capsys):
-    """The mesh scaling-efficiency contract: a round whose 2-shard
-    efficiency lands below the declared 0.70 floor regresses even as
-    the FIRST round to report the metric (the ceiling's
-    higher-is-better twin), while a healthy round rides clean."""
-    a = _round(tmp_path / "BENCH_r01.json",
-               {"backend": "cpu",
-                "mesh": {"value": 40.0, "scaling_efficiency": 0.55}})
-    rc = bench_report.report([a])
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert "mesh 2-shard scaling efficiency" in out
-    assert "floor" in out and "REGRESSED" in out
-    b = _round(tmp_path / "BENCH_r02.json",
-               {"backend": "cpu",
-                "mesh": {"value": 40.0, "scaling_efficiency": 0.82}})
-    assert bench_report.report([b]) == 0
-    capsys.readouterr()
-
-
-def test_bench_report_cross_backend_not_compared(tmp_path, capsys):
-    a = _round(tmp_path / "BENCH_r01.json",
-               {"backend": "cpu", "value": 100.0})
-    b = _round(tmp_path / "BENCH_r02.json",
-               {"backend": "tpu", "value": 10.0})
-    assert bench_report.report([a, b]) == 0
-    capsys.readouterr()
-
-
-def test_bench_report_error_rounds_are_outages_not_zeros(tmp_path,
-                                                         capsys):
-    a = _round(tmp_path / "BENCH_r01.json",
-               {"backend": "cpu", "value": 100.0})
-    # a dead round reports value 0.0 with an error attached — must not
-    # read as a 100% regression
-    b = _round(tmp_path / "BENCH_r02.json",
-               {"backend": "cpu", "value": 0.0, "error": "outage"})
-    c = _round(tmp_path / "BENCH_r03.json",
-               {"backend": "cpu", "value": 95.0})
-    assert bench_report.report([a, b, c]) == 0
-    out = capsys.readouterr().out
-    assert "—" in out
-
-
-def test_bench_report_empty_is_usage_error(tmp_path, capsys):
-    assert bench_report.report([]) == 254
-    capsys.readouterr()
-
-
-def test_bench_report_cli(tmp_path, capsys):
-    from jepsen_tpu import cli
-    a = _round(tmp_path / "BENCH_r01.json",
-               {"backend": "cpu", "value": 100.0})
-    b = _round(tmp_path / "BENCH_r02.json",
-               {"backend": "cpu", "value": 5.0})
-    rc = cli.run_cli(lambda tmap, args: tmap,
-                     argv=["bench-report", str(a), str(b)])
-    out = capsys.readouterr().out
-    assert rc == 1 and "REGRESSED" in out
-    rc = cli.run_cli(lambda tmap, args: tmap,
-                     argv=["bench-report", "--root", str(tmp_path)])
-    capsys.readouterr()
-    assert rc == 1
