@@ -14,9 +14,14 @@ with two layers:
   * a disk cache of serialized executables
     (`jax.experimental.serialize_executable`), keyed by a digest of
     (jax/jaxlib version, backend platform + device count, jitted
-    function name, input avals and shardings, kernel flags,
-    formulation), so a REPEAT sweep in a fresh process deserializes
-    instead of compiling.
+    function name, input avals and shardings — a mesh's axis names
+    and sizes included — kernel flags, formulation), so a REPEAT sweep
+    in a fresh process deserializes instead of compiling.
+
+Single-device and mesh-sharded dispatches both resolve here: an
+executable compiled from a jit with in/out shardings carries its
+collectives, and a lookup by fingerprint never traces, so a rebuilt
+jitted wrapper costs a dict probe, not a re-trace.
 
 Every lookup lands in exactly one of the `compile_cache_hits` /
 `compile_cache_misses` counters — the warm-path bench drives the miss
@@ -110,11 +115,20 @@ def _placement(a) -> list:
 
 
 def _sharding_key(a) -> str:
-    """An argument's placement: sharding type, device ids and spec. An
-    executable compiled for 8-shard inputs cannot run 1-device ones."""
+    """An argument's placement: sharding type, device ids and spec, and
+    for a mesh its axis names and sizes. An executable compiled for
+    8-shard inputs cannot run 1-device ones, and one compiled on a 2x2
+    dp x mp mesh is not the one for a 4x1 mesh over the same devices
+    with the same spec (the closure's constraint names `mp`). A
+    single-device sharding has no mesh: its key, and every executable
+    cached under it, stays as it was."""
     sh = a.sharding
     ids = [d.id for d in _placement(a)]
-    return f"{type(sh).__name__}{ids}{getattr(sh, 'spec', '')}"
+    key = f"{type(sh).__name__}{ids}{getattr(sh, 'spec', '')}"
+    mesh = getattr(sh, "mesh", None)
+    if mesh is not None:
+        key += str(tuple(mesh.shape.items()))
+    return key
 
 
 def _fingerprint(jitfn, args, key_parts: tuple) -> str:

@@ -15,9 +15,10 @@ off ⇒ zero new files, <1µs per dispatch):
     formulation + bucket geometry) key: the compiled executable's
     `cost_analysis()` (flops, bytes accessed, transcendentals) and
     `memory_analysis()` (argument/output/temp/generated-code bytes),
-    called from `aot.compiled_for` for single-device dispatches and
-    from `residency.ExecutableResidency.dispatch_fn` (via a one-time
-    `jit.lower()`, no compile) for mesh-sharded ones.
+    called from `aot.compiled_for` for every bucket dispatch, and —
+    with the AOT cache off — from
+    `residency.ExecutableResidency.dispatch_fn` (via a one-time
+    `jit.lower()`, no compile).
   * **join** — `begin_dispatch`/`close_dispatch` bracket each bucket
     dispatch's measured device window (the same enqueue→materialized
     window the tracer's device track records) and accumulate it into
@@ -103,23 +104,13 @@ def reset() -> None:
 
 def dispatch_cost_key(kw: dict, shape, single_device: bool,
                       donate: bool) -> tuple:
-    """THE cost key for one bucket dispatch. For single-device
-    dispatches it IS `ExecutableResidency.dispatch_key` (so the AOT
-    cache and the costdb key the same executable identically); mesh
-    dispatches build the same tuple with the mesh-resolved
-    formulation."""
+    """THE cost key for one bucket dispatch: it IS
+    `ExecutableResidency.dispatch_key`, so the AOT cache and the
+    costdb key the same executable identically, mesh-sharded or
+    not."""
     from ..parallel.residency import ExecutableResidency
-    if single_device:
-        return ExecutableResidency.dispatch_key(kw, shape, donate)
-    from ..checker.elle import kernels as K
-    use_pallas, use_int8 = K.resolve_formulation(single_device=False)
-    # the kernel-stats marker is appended only when on, mirroring
-    # ExecutableResidency.dispatch_key: the gate-off key never churns
-    return (kw.get("classify", True), kw.get("realtime", False),
-            kw.get("process_order", False), kw.get("fused"),
-            use_pallas, use_int8, bool(donate),
-            shape.n_keys, shape.max_pos, shape.n_txns) \
-        + (("stats",) if kw.get("with_stats") else ())
+    return ExecutableResidency.dispatch_key(kw, shape, donate,
+                                            single_device=single_device)
 
 
 def _cost_dict(obj) -> dict | None:
@@ -178,8 +169,9 @@ def observe(key_parts: tuple, args, obj, source: str) -> None:
     Compiled executable (`source="compiled"`, the aot.compiled_for
     path — memory analysis included) or a jitted fn
     (`source="lowered"`: one `lower()` trace, no compile — the
-    mesh-sharded path, where forcing a second XLA compile just to
-    read costs would defeat the point). Best-effort: never raises."""
+    path with the AOT cache off, where forcing a second XLA compile
+    just to read costs would defeat the point). Best-effort: never
+    raises."""
     if not enabled():
         return
     try:

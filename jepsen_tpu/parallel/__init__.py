@@ -123,11 +123,14 @@ def sharded_check_fn(mesh: Mesh | None, shape: K.BatchShape, *,
     pass explicit bools to race the formulations). use_int8 switches
     the squaring dots to int8×int8→int32 — exact for the boolean
     closure — and composes with use_pallas (the VMEM fusion and the
-    arithmetic are orthogonal levers). Mesh dispatches always stay
-    XLA so the compiler can insert collectives. Explicit arguments
-    win over the env. Memoized per (mesh, shape, flags) so repeated
-    same-shape dispatches (bucketed sweeps, per-key loops) compile
-    once."""
+    arithmetic are orthogonal levers). Mesh dispatches always take
+    the XLA formulation so the compiler can insert collectives; the
+    compiled executable carries them, so bucket dispatches resolve it
+    through the AOT executable map like single-device ones
+    (residency.ExecutableResidency). Explicit arguments win over the
+    env. Memoized per (mesh, shape, flags): a repeat call returns the
+    same jitted wrapper, and an evicted one costs a rebuild, never a
+    re-trace, on the bucket path."""
     if use_pallas and mesh is not None:
         # the Pallas squaring path bypasses the P('dp',None,'mp')
         # sharding constraint and would silently degrade sharded
@@ -609,11 +612,12 @@ def _quarantine_bucket(idx: list, stage: str, err, tr) -> list:
 
 def _dispatch_fn(bucket_mesh, shape: K.BatchShape, kw: dict, args,
                  donate: bool):
-    """The callable for one bucket dispatch: the jitted check fn, or —
-    single-device with the AOT cache on — a persistent compiled
-    executable (residency.ExecutableResidency over jepsen_tpu.aot)
-    keyed by the input avals + kernel flags + formulation, so a
-    repeat sweep pays zero XLA compiles."""
+    """The callable for one bucket dispatch, mesh-sharded or not: with
+    the AOT cache on, a persistent compiled executable
+    (residency.ExecutableResidency over jepsen_tpu.aot) keyed by the
+    input avals and shardings + kernel flags + formulation, so a
+    repeat dispatch never re-traces and a repeat sweep pays zero XLA
+    compiles; else the jitted check fn."""
     fn = sharded_check_fn(bucket_mesh, shape, donate=donate, **kw)
     return _residency.dispatch_fn(fn, bucket_mesh, shape, kw, args,
                                   donate)

@@ -11,12 +11,14 @@ dispatch loop per shard, and the future `serve` daemon (ROADMAP item
 buffers held resident across requests without re-owning the
 bookkeeping, so the two non-scheduling concerns live here:
 
-  * `ExecutableResidency` — resolves the callable for one dispatch:
-    the jitted fn as-is for mesh-sharded dispatches (XLA must insert
-    the collectives), or the persistent AOT-compiled executable
-    (jepsen_tpu.aot) for single-device dispatches, keyed by kernel
-    flags + resolved formulation + batch geometry, so a warm owner
-    pays zero XLA compiles however many dispatch loops it runs.
+  * `ExecutableResidency` — resolves the callable for one dispatch,
+    mesh-sharded or single-device alike: the persistent AOT-compiled
+    executable (jepsen_tpu.aot), keyed by kernel flags + resolved
+    formulation + batch geometry + input shardings (a mesh's axis
+    names and sizes among them), so a warm owner pays zero XLA
+    compiles — and zero re-traces — however many dispatch loops it
+    runs. A sharded executable carries the collectives XLA inserted
+    when it compiled.
   * `DeviceSlots` — ownership of donated device-buffer slots: the
     donation policy gate (single-device only, JEPSEN_TPU_DONATE_
     BUFFERS) plus the supervisor's process-wide slot ledger. A slot
@@ -36,32 +38,32 @@ from __future__ import annotations
 class ExecutableResidency:
     """Which compiled executables are resident for repeat dispatches.
 
-    jax's in-memory jit cache already dedups same-shape compiles within
-    a process; this layer adds the cross-process persistence (the AOT
-    executable cache) behind one stable key, so callers ask for "the
-    callable for this dispatch" and never learn how executables are
-    stored."""
+    jax's in-memory jit cache is keyed on the jitted function object,
+    so a rebuilt wrapper (an evicted `_sharded_check_fn_cached` entry)
+    traces again; the AOT executable map is keyed on what the compiled
+    artifact depends on, in memory and across processes, behind one
+    stable key, so callers ask for "the callable for this dispatch"
+    and never learn how executables are stored."""
 
     def dispatch_fn(self, fn, bucket_mesh, shape, kw: dict, args,
                     donate: bool):
-        """The callable for one bucket dispatch: `fn` (the jitted
-        check fn) for mesh-sharded dispatches, else the persistent
-        compiled executable when the AOT cache is on. Dispatches that
-        stay on the plain jitted fn (a mesh, or the AOT cache off)
-        still feed the device cost observatory — a one-time
-        `jit.lower()` per geometry reads `cost_analysis()` without
-        forcing a second XLA compile (obs.device, JEPSEN_TPU_COSTDB;
-        the compiled path captures inside aot.compiled_for)."""
-        if bucket_mesh is not None or not self._aot_enabled():
+        """The callable for one bucket dispatch: the persistent compiled
+        executable for `fn` over `args` when the AOT cache is on, found
+        by input avals and shardings before anything traces — so a
+        geometry met once never re-traces, however often its jitted
+        wrapper is rebuilt — else `fn` (the jitted check fn) itself.
+        On the jitted path a one-time `jit.lower()` per geometry still
+        feeds the device cost observatory (obs.device,
+        JEPSEN_TPU_COSTDB; the compiled path captures inside
+        aot.compiled_for)."""
+        key = self.dispatch_key(kw, shape, donate,
+                                single_device=bucket_mesh is None)
+        if not self._aot_enabled():
             from ..obs import device as device_obs
-            device_obs.observe(
-                device_obs.dispatch_cost_key(
-                    kw, shape, bucket_mesh is None, donate),
-                args, fn, source="lowered")
+            device_obs.observe(key, args, fn, source="lowered")
             return fn
         from .. import aot
-        return aot.compiled_for(
-            fn, args, self.dispatch_key(kw, shape, donate))
+        return aot.compiled_for(fn, args, key)
 
     @staticmethod
     def _aot_enabled() -> bool:
@@ -76,17 +78,21 @@ class ExecutableResidency:
         return aot.resident_count()
 
     @staticmethod
-    def dispatch_key(kw: dict, shape, donate: bool) -> tuple:
-        """The stable half of the AOT cache key for a single-device
-        dispatch: kernel flags + the RESOLVED closure formulation +
-        batch geometry (aot itself adds input avals, backend topology
-        and jax/jaxlib versions). A kernel-stats dispatch
-        (JEPSEN_TPU_KERNEL_STATS) returns a second output and so
-        compiles a different executable — the marker is APPENDED only
-        when the flag is on, so the gate-off key (and every cached
-        executable keyed under it) is byte-identical to before."""
+    def dispatch_key(kw: dict, shape, donate: bool, *,
+                     single_device: bool = True) -> tuple:
+        """The stable half of the AOT cache key for one dispatch: kernel
+        flags + the closure formulation RESOLVED for the dispatch's kind
+        (a mesh never takes Pallas) + batch geometry (aot itself adds
+        input avals and shardings — a mesh's axis names and sizes with
+        them — backend topology and jax/jaxlib versions). A
+        kernel-stats dispatch (JEPSEN_TPU_KERNEL_STATS) returns a
+        second output and so compiles a different executable — the
+        marker is APPENDED only when the flag is on, so the gate-off
+        key (and every cached executable keyed under it) is
+        byte-identical to before."""
         from ..checker.elle import kernels as K
-        use_pallas, use_int8 = K.resolve_formulation(single_device=True)
+        use_pallas, use_int8 = K.resolve_formulation(
+            single_device=single_device)
         return (kw.get("classify", True), kw.get("realtime", False),
                 kw.get("process_order", False), kw.get("fused"),
                 use_pallas, use_int8, donate,

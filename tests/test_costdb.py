@@ -90,6 +90,14 @@ class TestPeakTable:
         assert key[7] == 16 and key[8] == 24 and key[9] == 128
         assert key == device_obs.dispatch_cost_key(
             kw, shape, single_device=True, donate=True)
+        # a mesh dispatch keys the same layout (its mesh rides the AOT
+        # fingerprint's shardings), and the costdb joins it the same way
+        mesh_key = ExecutableResidency.dispatch_key(
+            kw, shape, donate=False, single_device=False)
+        assert len(mesh_key) == len(device_obs._KEY_FIELDS)
+        assert mesh_key[6] is False and mesh_key[7:] == key[7:]
+        assert mesh_key == device_obs.dispatch_cost_key(
+            kw, shape, single_device=False, donate=False)
 
 
 # ---------------------------------------------------------------------------

@@ -238,6 +238,32 @@ class TestGateOffFree:
         assert device_obs.dispatch_cost_key(
             {**kw, "with_stats": True}, shape, False, False)[-1] \
             == "stats"
+        # naming the single-device kind explicitly changes nothing
+        assert ExecutableResidency.dispatch_key(
+            kw, shape, donate=True, single_device=True) == off
+
+    def test_single_device_fingerprint_golden(self):
+        """A single-device dispatch's AOT fingerprint is byte-identical
+        to the one every cached executable was written under: a mesh's
+        axis names and sizes enter only the keys of mesh-sharded
+        inputs (a SingleDeviceSharding has no mesh)."""
+        import jax
+        from jepsen_tpu import aot, parallel
+        from jepsen_tpu.parallel.residency import ExecutableResidency
+        shape = K.BatchShape(n_txns=128, n_appends=8, n_reads=8,
+                             n_keys=8, max_pos=8)
+        kw = {"classify": True, "realtime": False,
+              "process_order": False, "fused": True}
+        key = ExecutableResidency.dispatch_key(kw, shape, donate=True)
+        fn = parallel.sharded_check_fn(None, shape, donate=True, **kw)
+        dev = jax.devices()[0]
+        args = tuple(jax.device_put(np.zeros(s, np.int32), dev)
+                     for s in [(2, 8, 3), (2, 8, 3), (2, 128), (2, 128),
+                               (2, 128), (2,)])
+        assert aot._sharding_key(args[0]) == "SingleDeviceSharding[0]"
+        assert aot._fingerprint(fn, args, key) == (
+            "b7b4d66cd0bfb0882abb2bae02daaa11"
+            "513d31fecdbdc3a16502a21738749a0d")
 
     def test_gate_off_overhead_sub_microsecond(self, monkeypatch):
         """The added per-record code path with the gate off is one

@@ -395,6 +395,64 @@ class TestAotCache:
             aot.clear_memory()
         assert len(list(aot.cache_dir().glob("*.jtx"))) == 2
 
+    def test_mesh_shape_is_part_of_the_key(self):
+        """A 2x2 dp x mp mesh and a 4x1 dp mesh over the same four
+        devices, with the same P('dp') inputs, compile different
+        executables (the closure is constrained over `mp`): their
+        fingerprints differ."""
+        import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        enc = synth.synth_encoded_history(64, K=8)
+        shape = K.BatchShape.plan([enc] * 4)
+        single = parallel.shard_batch(None, K.pack_batch([enc] * 4, shape))
+        devs = np.asarray(jax.devices()[:4])
+        kp = ("mesh-key-test",)
+        prints = []
+        for mesh in (Mesh(devs.reshape(2, 2), ("dp", "mp")),
+                     Mesh(devs.reshape(4, 1), ("dp", "mp"))):
+            fn = parallel.sharded_check_fn(mesh, shape)
+            args = tuple(jax.device_put(a, NamedSharding(mesh, P("dp")))
+                         for a in single)
+            prints.append(aot._fingerprint(fn, args, kp))
+            assert str(tuple(mesh.shape.items())) \
+                in aot._sharding_key(args[0])
+        assert prints[0] != prints[1]
+
+    @pytest.mark.parametrize("layer", ["memory", "disk"])
+    def test_mesh_pass_resolves_through_aot(self, layer):
+        """Two check_bucketed passes on a 2x2 dp x mp mesh over buckets
+        of several geometries, every jitted wrapper evicted between
+        them (and, for `disk`, the in-memory executables too, so the
+        disk layer alone answers): the second pass finds each bucket's
+        executable by fingerprint — no miss, no trace — and its
+        verdicts are the single-device ones."""
+        import jax
+        from jepsen_tpu import jaxtrace
+        jaxtrace.install()
+        mesh = parallel.make_mesh(jax.devices()[:4])
+        assert dict(mesh.shape) == {"dp": 2, "mp": 2}
+        # padded to 512, 384 and 128 txns; at this budget (two buckets
+        # in flight, each at most 2 x 512^2 cells) one bucket each
+        encs = [synth.synth_encoded_history(T, K=6, inject_cycle=c)
+                for T in (450, 300, 40) for c in (False, True)]
+        budget = 2 * 2 * 512 * 512
+        want = parallel.check_bucketed(encs)
+        tr = trace.fresh_run("mesh-cold")
+        assert parallel.check_bucketed(encs, mesh,
+                                       budget_cells=budget) == want
+        assert ctr(tr, "compile_cache_misses") \
+            == ctr(tr, "buckets_dispatched") == 3
+        parallel._sharded_check_fn_cached.cache_clear()
+        if layer == "disk":
+            aot.clear_memory()
+        tr = trace.fresh_run(f"mesh-warm-{layer}")
+        assert parallel.check_bucketed(encs, mesh,
+                                       budget_cells=budget) == want
+        assert ctr(tr, "compile_cache_misses") == 0
+        assert ctr(tr, "compile_cache_hits") \
+            == ctr(tr, "buckets_dispatched") == 3
+        assert ctr(tr, "jit_traces") == 0
+
     def test_repeat_sweep_all_hits(self, tmp_path):
         dirs = append_dirs(tmp_path, n=4, T=30)
         encs = warm_encs(dirs)
