@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ... import trace
 from ...devices import default_devices
 from ...util import pad_to_multiple
 from .encode import EncodedHistory, effective_complete_index
@@ -807,43 +808,53 @@ def check_edge_batch(per_history: list[dict], realtime: bool = False,
     n = len(per_history)
     devices = devices if devices is not None else default_devices()
     per_history = pad_to_multiple(per_history, len(devices))
-    p = pack_edge_matrices(per_history)
+    tr = trace.get_current()
+    edges = sum(len(h["edges"]) for h in per_history)
+    with tr.phase_span("edge_pack", B=len(per_history),
+                       edges=edges) as span:
+        p = pack_edge_matrices(per_history)
+        span.note(T=p["T"])
+    tr.counter("wr_edges_packed").inc(edges)
     names = ("ww", "wr", "rw", "invoke_index", "complete_index",
              "process", "n_txns")
     # device_put straight from numpy: going through jnp.asarray first
     # would commit each [B,T,T] matrix whole onto device 0 before the
     # dp sharding ever applied.
-    if len(devices) > 1:
-        mesh = jax.sharding.Mesh(np.asarray(devices), ("dp",))
-        sharding = jax.sharding.NamedSharding(
-            mesh, jax.sharding.PartitionSpec("dp"))
-        args = [jax.device_put(p[k], sharding) for k in names]
-    else:
-        args = [jax.device_put(p[k], devices[0] if devices else None)
-                for k in names]
+    with tr.phase_span("h2d", B=len(per_history), T=p["T"]):
+        if len(devices) > 1:
+            mesh = jax.sharding.Mesh(np.asarray(devices), ("dp",))
+            sharding = jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec("dp"))
+            args = [jax.device_put(p[k], sharding) for k in names]
+        else:
+            args = [jax.device_put(p[k], devices[0] if devices else None)
+                    for k in names]
     use_pallas, use_int8 = resolve_formulation(
         single_device=len(devices) == 1)
     if fused is None:
         fused = fused_classify_enabled()
     with_stats = stats_out is not None
-    out = classify_matrices_device(
-        *args, steps=closure_steps(p["T"]), classify=classify,
-        realtime=realtime, process_order=process_order,
-        use_pallas=use_pallas, use_int8=use_int8, fused=fused,
-        with_stats=with_stats)
+    with tr.phase_span("dispatch", B=len(per_history), T=p["T"]):
+        out = classify_matrices_device(
+            *args, steps=closure_steps(p["T"]), classify=classify,
+            realtime=realtime, process_order=process_order,
+            use_pallas=use_pallas, use_int8=use_int8, fused=fused,
+            with_stats=with_stats)
+    tr.counter("buckets_dispatched").inc()
     flags, dev_stats = out if with_stats else (out, None)
     # the np.asarray below is an implicit device wait: bound it with
     # the dispatch watchdog so a wedged device can't hang the wr sweep
     # (JEPSEN_TPU_DISPATCH_TIMEOUT_S; no-op when the gate is off)
     from ...parallel import _block_flags
-    from ... import trace as _trace
-    flags = _block_flags(flags, _trace.get_current())
-    if with_stats:
-        rows = np.asarray(dev_stats)[:n]
-        stats_out.extend(
-            stats_row(rows[i], n_txns=per_history[i]["n"],
-                      t_pad=p["T"]) for i in range(n))
-    return [flags_to_names(int(w)) for w in np.asarray(flags)[:n]]
+    with tr.phase_span("collect"):
+        words = np.asarray(_block_flags(flags, tr))[:n]
+        if with_stats:
+            rows = np.asarray(dev_stats)[:n]
+            stats_out.extend(
+                stats_row(rows[i], n_txns=per_history[i]["n"],
+                          t_pad=p["T"]) for i in range(n))
+    tr.counter("buckets_resolved").inc()
+    return [flags_to_names(int(w)) for w in words]
 
 
 def check_edge_batch_bucketed(per_history: list[dict],
