@@ -604,21 +604,34 @@ class Linearizable(Checker):
         register-shaped at all. The kernels implement CAS-register
         semantics from a nil initial state, so any other model routes
         to CPU wholesale. Verdicts only ever degrade toward the
-        oracle, never diverge from it."""
+        oracle, never diverge from it.
+
+        Where `engine()` is "tpu", a history may arrive already routed
+        and encoded, as a `DenseEncoded` (the register sweep's ingest
+        workers encode its keys): it joins the dense tier as it is."""
+        engine = self.engine()
+        if engine == "race":
+            if stats_out is not None:
+                stats_out.extend(None for _ in histories)
+            return self._race(histories)
+        if engine == "tpu":
+            return self._device_batch(histories, stats_out=stats_out)
+        out = []
+        for hs in histories:
+            sd: dict | None = {} if stats_out is not None else None
+            out.append(self._cpu(hs, search_stats=sd))
+            if stats_out is not None:
+                stats_out.append(sd or None)
+        return out
+
+    def engine(self) -> str:
+        """Where check_batch sends a batch: "tpu" (the tiered device
+        pipeline), "race" (device against CPU) or "cpu"."""
         # Model eligibility first: only the CPU engine implements
         # models other than the nil-initial CAS register.
-        def cpu_all():
-            out = []
-            for hs in histories:
-                sd: dict | None = {} if stats_out is not None else None
-                out.append(self._cpu(hs, search_stats=sd))
-                if stats_out is not None:
-                    stats_out.append(sd or None)
-            return out
-
         if not (type(self.model) is model.CASRegister
                 and self.model.value is None):
-            return cpu_all()
+            return "cpu"
         from ..devices import resolve_backend
         backend = self.backend
         if backend == "auto":
@@ -628,14 +641,8 @@ class Linearizable(Checker):
             from .. import gates
             backend = gates.get("JEPSEN_TPU_BACKEND") or "auto"
         if backend == "race":
-            if resolve_backend("auto") != "tpu":
-                return cpu_all()
-            if stats_out is not None:
-                stats_out.extend(None for _ in histories)
-            return self._race(histories)
-        if resolve_backend(self.backend) != "tpu":
-            return cpu_all()
-        return self._device_batch(histories, stats_out=stats_out)
+            return "race" if resolve_backend("auto") == "tpu" else "cpu"
+        return "tpu" if resolve_backend(self.backend) == "tpu" else "cpu"
 
     #: losing race dispatches still draining in background threads;
     #: joined at interpreter exit so teardown can't kill a thread
@@ -749,32 +756,19 @@ class Linearizable(Checker):
         # the device tier it fits and encoded for it
         with tr.phase_span("knossos_pack", keys=len(histories)):
             for i, hs in enumerate(histories):
-                try:
-                    dense_encs.append(dense.encode_dense_history(hs))
+                if isinstance(hs, kenc.DenseEncoded):
+                    tier, enc = kenc.DENSE, hs
+                else:
+                    tier, enc = kenc.route_register_history(
+                        hs, self.frontier)
+                if tier == kenc.DENSE:
+                    dense_encs.append(enc)
                     dense_idx.append(i)
-                except kenc.EncodingError:
-                    try:
-                        enc = kenc.encode_register_history(hs)
-                        # Feasibility gate: every simultaneously-open
-                        # write or unknown-value read doubles the frontier
-                        # (they apply in any order); open cas ops and
-                        # known-value reads prune on state mismatch —
-                        # empirically contributing about half a doubling
-                        # each. If the estimated closure can't fit the
-                        # arena, the kernel would burn a full device pass
-                        # only to report overflow (round 4's
-                        # tiers={"wgl": 8}); predictably-infeasible
-                        # histories go straight to the oracle. The
-                        # kernel's own overflow fallback still catches the
-                        # ones the estimate admits.
-                        budget = 2 * (max(self.frontier, 1).bit_length() - 1)
-                        if enc.half_doublings_peak > budget:
-                            cpu_idx.append(i)
-                        else:
-                            front_encs.append(enc)
-                            front_idx.append(i)
-                    except kenc.EncodingError:
-                        cpu_idx.append(i)
+                elif tier == kenc.FRONTIER:
+                    front_encs.append(enc)
+                    front_idx.append(i)
+                else:
+                    cpu_idx.append(i)
         results: list[dict | None] = [None] * len(histories)
         if dense_encs:
             ds: list | None = [] if with_stats else None

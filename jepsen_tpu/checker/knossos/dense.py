@@ -50,117 +50,10 @@ import numpy as np
 from ... import trace
 from ...devices import default_devices
 from ...util import pad_to_multiple
-from .encode import CAS, READ, WRITE, EncodingError, _reduced_seq
-
-_F_CODES = {"read": READ, "write": WRITE, "cas": CAS}
-
-
-@dataclass
-class DenseEncoded:
-    """Per-completion slot-register timeline for one history."""
-
-    regs: np.ndarray       # [C, S, 4] int32: (f|-1, a1, a2, known)
-    comp_slot: np.ndarray  # [C] int32: slot completing at each step
-    n_steps: int
-    n_slots: int
-    n_values: int
-    n_ops: int             # determinate+indeterminate ops linearized over
-
-
-def encode_dense_history(raw_history: list[dict], max_slots: int = 14,
-                         max_values: int = 64) -> DenseEncoded:
-    """Compile one register history to the dense kernel's timeline."""
-    hist = _reduced_seq(raw_history)   # dict-free reduce_history twin
-
-    # Which invocations never complete determinately? (info ops, and
-    # open calls at history end). Info *reads* are dropped entirely.
-    opens: dict = {}
-    determinate: set[int] = set()
-    for i, (kind, p, f, v) in enumerate(hist):
-        if kind == 0:
-            opens[p] = i
-        elif p in opens:
-            j = opens.pop(p)
-            if kind != 1:
-                determinate.add(j)
-
-    intern: dict = {None: 0}
-    values: list = [None]
-
-    vkind: dict[int, str] = {}
-
-    def vid(v):
-        # same list/tuple ambiguity rule as encode.vid: equating what
-        # the model distinguishes is unencodable
-        kind = ("list" if isinstance(v, list)
-                else "tuple" if isinstance(v, tuple) else "scalar")
-        if kind == "list":
-            v = tuple(v)
-        i = intern.get(v)
-        fresh = i is None
-        if fresh:
-            i = len(values)
-            intern[v] = i
-            values.append(v)
-        if kind != "scalar" and vkind.setdefault(i, kind) != kind:
-            raise EncodingError(
-                "value interned from both a list and an equal tuple")
-        if fresh:
-            if len(values) > max_values:
-                raise EncodingError(
-                    f"more than {max_values} distinct register values")
-        return i
-
-    S = max_slots
-    regs = np.full((S, 4), -1, np.int32)
-    regs[:, 1:] = 0
-    slot_of: dict = {}
-    free = list(range(S))  # kept sorted: lowest slot first, compact peak
-    steps_regs: list[np.ndarray] = []
-    steps_comp: list[int] = []
-    n_ops = 0
-    peak = 1
-
-    for i, (kind, p, fname, v) in enumerate(hist):
-        if kind == 0:
-            f = _F_CODES.get(fname)
-            if f is None:
-                raise EncodingError(f"unencodable op f={fname!r}")
-            if i not in determinate and f == READ:
-                continue  # reduction 1: info reads constrain nothing
-            if not free:
-                raise EncodingError(
-                    f"concurrency exceeds {S} pending slots")
-            slot = free.pop(0)
-            peak = max(peak, slot + 1)
-            slot_of[p] = slot
-            if f == CAS:
-                if not (isinstance(v, (list, tuple)) and len(v) == 2):
-                    raise EncodingError(f"cas value {v!r} is not [old new]")
-                row = (f, vid(v[0]), vid(v[1]), 1)
-            elif f == WRITE:
-                row = (f, vid(v), 0, 1)
-            else:
-                known = 0 if v is None else 1
-                row = (f, vid(v) if known else 0, 0, known)
-            regs[slot] = row
-            n_ops += 1
-        elif p in slot_of:
-            slot = slot_of.pop(p)
-            if kind == 1:
-                continue  # return at infinity: slot stays occupied
-            steps_regs.append(regs.copy())
-            steps_comp.append(slot)
-            regs[slot] = (-1, 0, 0, 0)
-            free.append(slot)
-            free.sort()
-
-    C = len(steps_regs)
-    return DenseEncoded(
-        regs=(np.stack(steps_regs)[:, :peak] if C
-              else np.full((0, peak, 4), -1, np.int32)),
-        comp_slot=np.asarray(steps_comp, np.int32),
-        n_steps=C, n_slots=peak, n_values=len(values), n_ops=n_ops)
+from .encode import CAS, READ, WRITE
+# the host encoder needs no JAX (ingest workers run it): it lives in
+# `.encode`, re-exported here for its callers and stored pickles
+from .encode import DenseEncoded, encode_dense_history  # noqa: F401
 
 
 @dataclass(frozen=True)

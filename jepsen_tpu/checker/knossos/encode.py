@@ -259,6 +259,149 @@ def encode_register_history(raw_history: list[dict],
         uncond_peak=uncond_peak, half_doublings_peak=half_peak)
 
 
+@dataclass
+class DenseEncoded:
+    """Per-completion slot-register timeline for one history."""
+
+    regs: np.ndarray       # [C, S, 4] int32: (f|-1, a1, a2, known)
+    comp_slot: np.ndarray  # [C] int32: slot completing at each step
+    n_steps: int
+    n_slots: int
+    n_values: int
+    n_ops: int             # determinate+indeterminate ops linearized over
+
+
+def encode_dense_history(raw_history: list[dict], max_slots: int = 14,
+                         max_values: int = 64) -> DenseEncoded:
+    """Compile one register history to the dense-bitset kernel's
+    (`.dense`) per-completion timeline."""
+    hist = _reduced_seq(raw_history)   # dict-free reduce_history twin
+
+    # Which invocations never complete determinately? (info ops, and
+    # open calls at history end). Info *reads* are dropped entirely.
+    opens: dict = {}
+    determinate: set[int] = set()
+    for i, (kind, p, f, v) in enumerate(hist):
+        if kind == 0:
+            opens[p] = i
+        elif p in opens:
+            j = opens.pop(p)
+            if kind != 1:
+                determinate.add(j)
+
+    intern: dict = {None: 0}
+    values: list = [None]
+
+    vkind: dict[int, str] = {}
+
+    def vid(v):
+        # encode_register_history's list/tuple ambiguity rule: equating what
+        # the model distinguishes is unencodable
+        kind = ("list" if isinstance(v, list)
+                else "tuple" if isinstance(v, tuple) else "scalar")
+        if kind == "list":
+            v = tuple(v)
+        i = intern.get(v)
+        fresh = i is None
+        if fresh:
+            i = len(values)
+            intern[v] = i
+            values.append(v)
+        if kind != "scalar" and vkind.setdefault(i, kind) != kind:
+            raise EncodingError(
+                "value interned from both a list and an equal tuple")
+        if fresh:
+            if len(values) > max_values:
+                raise EncodingError(
+                    f"more than {max_values} distinct register values")
+        return i
+
+    S = max_slots
+    regs = np.full((S, 4), -1, np.int32)
+    regs[:, 1:] = 0
+    slot_of: dict = {}
+    free = list(range(S))  # kept sorted: lowest slot first, compact peak
+    steps_regs: list[np.ndarray] = []
+    steps_comp: list[int] = []
+    n_ops = 0
+    peak = 1
+
+    for i, (kind, p, fname, v) in enumerate(hist):
+        if kind == 0:
+            f = _F_CODES.get(fname)
+            if f is None:
+                raise EncodingError(f"unencodable op f={fname!r}")
+            if i not in determinate and f == READ:
+                continue  # reduction 1: info reads constrain nothing
+            if not free:
+                raise EncodingError(
+                    f"concurrency exceeds {S} pending slots")
+            slot = free.pop(0)
+            peak = max(peak, slot + 1)
+            slot_of[p] = slot
+            if f == CAS:
+                if not (isinstance(v, (list, tuple)) and len(v) == 2):
+                    raise EncodingError(f"cas value {v!r} is not [old new]")
+                row = (f, vid(v[0]), vid(v[1]), 1)
+            elif f == WRITE:
+                row = (f, vid(v), 0, 1)
+            else:
+                known = 0 if v is None else 1
+                row = (f, vid(v) if known else 0, 0, known)
+            regs[slot] = row
+            n_ops += 1
+        elif p in slot_of:
+            slot = slot_of.pop(p)
+            if kind == 1:
+                continue  # return at infinity: slot stays occupied
+            steps_regs.append(regs.copy())
+            steps_comp.append(slot)
+            regs[slot] = (-1, 0, 0, 0)
+            free.append(slot)
+            free.sort()
+
+    C = len(steps_regs)
+    return DenseEncoded(
+        regs=(np.stack(steps_regs)[:, :peak] if C
+              else np.full((0, peak, 4), -1, np.int32)),
+        comp_slot=np.asarray(steps_comp, np.int32),
+        n_steps=C, n_slots=peak, n_values=len(values), n_ops=n_ops)
+
+
+#: the device tiers `route_register_history` picks from
+DENSE, FRONTIER, CPU = "dense", "frontier", "cpu"
+
+
+def route_register_history(raw_history: list[dict], frontier: int):
+    """The tier one register history goes to, with its encoding for it:
+    (DENSE, DenseEncoded) when it fits the dense grid, else (FRONTIER,
+    EncodedRegisterHistory) when the bounded-frontier kernel's arena of
+    `frontier` configurations can plausibly hold it, else (CPU, None).
+    One routing for the main thread's tiered pipeline and the register
+    sweep's ingest workers, so their verdicts agree by construction."""
+    try:
+        return DENSE, encode_dense_history(raw_history)
+    except EncodingError:
+        pass
+    try:
+        enc = encode_register_history(raw_history)
+    except EncodingError:
+        return CPU, None
+    # Feasibility gate: every simultaneously-open write or unknown-value
+    # read doubles the frontier (they apply in any order); open cas ops
+    # and known-value reads prune on state mismatch — empirically
+    # contributing about half a doubling each. If the estimated closure
+    # can't fit the arena, the kernel would burn a full device pass
+    # only to report overflow (round 4's tiers={"wgl": 8});
+    # predictably-infeasible histories go straight to the oracle. The
+    # kernel's own overflow fallback still catches the ones the
+    # estimate admits.
+    budget = 2 * (max(frontier, 1).bit_length() - 1)
+    if enc.half_doublings_peak > budget:
+        return CPU, None
+    return FRONTIER, enc
+
+
 @dataclass(frozen=True)
 class RegisterBatchShape:
     """Static padding plan for a batch of encoded register histories."""

@@ -1204,9 +1204,11 @@ def _analyze_store_register(store: Store, run_dirs: list,
     key's subhistory from EVERY run goes down in one tiered device
     sweep (dense grid -> bounded frontier -> CPU re-run), then verdicts
     regroup per run — the etcd-shaped batch sweep of BASELINE config
-    #1. Runs whose client ops aren't register-shaped fall back to
-    their own stored checker."""
-    from . import independent, ingest
+    #1. The load workers relift and split each run and, on the device
+    tiers, dense-encode its keys; the main thread only regroups and
+    dispatches. Runs whose client ops aren't register-shaped fall back
+    to their own stored checker."""
+    from . import ingest
     from .checker import linearizable, models
 
     # auto resolves to the device kernels when an accelerator is
@@ -1214,46 +1216,26 @@ def _analyze_store_register(store: Store, run_dirs: list,
     c = linearizable(models.cas_register(), backend="auto")
     tr = trace.get_current()
 
-    subs: list[list] = []          # flattened subhistories
-    owners: list[tuple[int, object]] = []   # (run index, key)
-    fallback: list[int] = []
+    # with the device tiers, the load workers also encode each key that
+    # fits the dense grid; every other key comes back raw
+    frontier = c.frontier if c.engine() == "tpu" else None
     with tr.phase_span("register_load", runs=len(run_dirs)):
-        hists = ingest.parallel_load(run_dirs)
-    for i, (d, hist) in enumerate(zip(run_dirs, hists)):
-        if isinstance(hist, Exception):
-            fallback.append(i)
-            continue
-        with tr.phase_span("register_split") as split:
-            hist = independent.relift_history(hist)
-            client_fs = {o.get("f") for o in hist
-                         if o.get("process") != "nemesis"
-                         and o.get("f") is not None}
-            by_key = None
-            if client_fs and client_fs <= {"read", "write", "cas"}:
-                # one pass, all keys
-                by_key = independent.subhistories(hist)
-                split.note(keys=len(by_key))
-        if by_key is None:
-            fallback.append(i)
-            continue
-        ks = list(by_key)
-        # a plain cas value is [old new] (scalars); a LIFTED cas value
-        # is [key [old new]] — second element a list marks it lifted
-        if not ks and any(
-                isinstance(o.get("value"), (list, tuple))
-                and len(o["value"]) == 2
-                and (o.get("f") != "cas"
-                     or isinstance(o["value"][1], (list, tuple)))
-                for o in hist if o.get("process") != "nemesis"):
-            # looks lifted ([k v] values) but relift declined (e.g. no
-            # ok read survived the faults): checking it as ONE register
-            # would feed the oracle [key value] pairs — let the run's
-            # own stored checker handle it instead
-            fallback.append(i)
-            continue
-        for k in (ks or [None]):
-            subs.append(by_key[k] if ks else hist)
-            owners.append((i, k))
+        recs = ingest.parallel_split_registers(run_dirs, frontier)
+    subs: list = []     # per key: its subhistory, or its DenseEncoded
+    owners: list[tuple[int, object, int]] = []  # (run index, key, ops)
+    fallback: list[int] = []
+    with tr.phase_span("register_split") as split:
+        for i, rec in enumerate(recs):
+            if rec is None or isinstance(rec, Exception):
+                fallback.append(i)
+                continue
+            for k, n, sub in rec:
+                subs.append(sub)
+                owners.append((i, k, n))
+        split.note(keys=len(subs))
+    raw = sum(isinstance(s, list) for s in subs)
+    tr.counter("register_keys_preencoded").inc(len(subs) - raw)
+    tr.counter("register_keys_raw").inc(raw)
 
     from .obs import search as search_obs
     ksouts: list | None = [] if search_obs.enabled() else None
@@ -1262,7 +1244,7 @@ def _analyze_store_register(store: Store, run_dirs: list,
             if subs else []
     except Exception:
         # one malformed run must not sink the sweep: re-dispatch each
-        # subhistory in isolation, degrading only the broken ones
+        # key in isolation, degrading only the broken ones
         log.warning("batched register sweep failed; isolating per key",
                     exc_info=True)
         results = []
@@ -1275,11 +1257,10 @@ def _analyze_store_register(store: Store, run_dirs: list,
                                 "error": repr(e)[:200]})
     per_run: dict[int, dict] = {}
     per_run_stats: dict[int, list] = {}
-    for j, ((i, k), res) in enumerate(zip(owners, results)):
+    for j, ((i, k, n), res) in enumerate(zip(owners, results)):
         per_run.setdefault(i, {})[k] = res
         if ksouts is not None:
-            per_run_stats.setdefault(i, []).append(
-                (k, len(subs[j]), ksouts[j]))
+            per_run_stats.setdefault(i, []).append((k, n, ksouts[j]))
 
     with tr.phase_span("register_write", runs=len(run_dirs)):
         return _write_register_verdicts(run_dirs, fallback, per_run,

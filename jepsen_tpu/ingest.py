@@ -209,6 +209,63 @@ def _load_worker(run_dir):
         return e
 
 
+def split_register_run(hist: list, frontier: int | None):
+    """One stored run's history split for the register sweep: None when
+    the run goes to its own stored checker, else its keys in first-seen
+    order as (key, op count, subhistory). With `frontier` (the sweep's
+    checker takes the device tiers, with that frontier arena), a key
+    that fits the dense tier carries its DenseEncoded in place of its
+    subhistory; the other keys stay raw for the main thread's tiered
+    path."""
+    from . import independent
+    hist = independent.relift_history(hist)
+    client_fs = {o.get("f") for o in hist
+                 if o.get("process") != "nemesis"
+                 and o.get("f") is not None}
+    if not (client_fs and client_fs <= {"read", "write", "cas"}):
+        return None
+    # one pass, all keys
+    by_key = independent.subhistories(hist)
+    ks = list(by_key)
+    # a plain cas value is [old new] (scalars); a LIFTED cas value
+    # is [key [old new]] — second element a list marks it lifted
+    if not ks and any(
+            isinstance(o.get("value"), (list, tuple))
+            and len(o["value"]) == 2
+            and (o.get("f") != "cas"
+                 or isinstance(o["value"][1], (list, tuple)))
+            for o in hist if o.get("process") != "nemesis"):
+        # looks lifted ([k v] values) but relift declined (e.g. no
+        # ok read survived the faults): checking it as ONE register
+        # would feed the oracle [key value] pairs — let the run's
+        # own stored checker handle it instead
+        return None
+    out = []
+    for k in (ks or [None]):
+        sub = by_key[k] if ks else hist
+        payload = sub
+        if frontier is not None:
+            from .checker.knossos import encode as kenc
+            try:
+                tier, enc = kenc.route_register_history(sub, frontier)
+            except Exception:
+                # the main thread's tiered path meets the same error
+                # and isolates the key, as it would have unsplit
+                tier = None
+            if tier == kenc.DENSE:
+                payload = enc
+        out.append((k, len(sub), payload))
+    return out
+
+
+def _register_worker(args):
+    run_dir, frontier = args
+    try:
+        return split_register_run(load_history_dir(run_dir), frontier)
+    except Exception as e:
+        return e
+
+
 def _spawn_safe() -> bool:
     """Can a spawn-context worker actually boot? spawn re-imports
     __main__ by its spec or, failing that, its `__file__`; when that
@@ -258,6 +315,18 @@ def parallel_load(run_dirs: Sequence[str | os.PathLike],
     sweep). Returns histories or per-run Exception objects, aligned
     with run_dirs."""
     return _pool_map(_load_worker, list(run_dirs), processes)
+
+
+def parallel_split_registers(run_dirs: Sequence[str | os.PathLike],
+                             frontier: int | None,
+                             processes: int | None = None) -> list:
+    """`split_register_run` over many run dirs via a process pool, each
+    worker loading its own history: only the keys, and the dense
+    encodings where `frontier` asks for them, cross back to the parent.
+    Returns the per-run records, None, or per-run Exception objects,
+    aligned with run_dirs."""
+    return _pool_map(_register_worker,
+                     [(d, frontier) for d in run_dirs], processes)
 
 
 def parallel_encode(run_dirs: Sequence[str | os.PathLike],

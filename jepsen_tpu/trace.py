@@ -82,7 +82,8 @@ DECLARED_METRICS: dict[str, frozenset] = {
         "native_fallback", "oom_retries", "pad_waste_cells",
         "planner.cold_starts", "planner.decisions",
         "planner.fallbacks", "planner.pred_checked",
-        "quarantined", "register_cpu_routed", "runs_verdicted",
+        "quarantined", "register_cpu_routed",
+        "register_keys_preencoded", "register_keys_raw", "runs_verdicted",
         "serve_backpressure", "serve_folds", "serve_replays",
         "serve_requests", "serve_verdicts", "shm_bytes",
         "shm_stale_reclaimed", "sidecar_upgrades", "split.native",

@@ -149,6 +149,18 @@ def _encode_in_worker(run_dir):
             os.environ.get("JAX_PLATFORMS"), backends)
 
 
+def _register_history():
+    """A lifted two-key register history as stored: [k v] values."""
+    hist = []
+    for k in ("a", "b"):
+        for f, v in (("write", 1), ("read", 1), ("cas", [1, 2])):
+            hist.append({"type": "invoke", "process": 0, "f": f,
+                         "value": [k, None if f == "read" else v]})
+            hist.append({"type": "ok", "process": 0, "f": f,
+                         "value": [k, v]})
+    return [{**o, "index": i, "time": i} for i, o in enumerate(hist)]
+
+
 class TestPoolWorkersLeaveTheChip:
     def test_spawned_encode_initialises_no_backend(self, tmp_path,
                                                    monkeypatch):
@@ -166,3 +178,24 @@ class TestPoolWorkersLeaveTheChip:
         assert ok
         assert platforms is None
         assert backends == []
+
+    def test_spawned_register_worker_never_imports_jax(self, tmp_path,
+                                                        monkeypatch):
+        # the register sweep's workers encode for the device tiers on
+        # the host alone; `eval` probes the worker's own sys.modules
+        # (this module imports JAX, so a function of its own would)
+        import multiprocessing as mp
+        from concurrent.futures import ProcessPoolExecutor
+        from jepsen_tpu.checker.knossos import encode as kenc
+        d = write_run(tmp_path, "r0", _register_history())
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        with ProcessPoolExecutor(
+                max_workers=1, mp_context=mp.get_context("spawn")) as ex:
+            rec = ex.submit(ingest._register_worker,
+                            (str(d), 512)).result(timeout=300)
+            jax_loaded = ex.submit(
+                eval, "'jax' in __import__('sys').modules").result(
+                    timeout=60)
+        assert [k for k, _n, _e in rec] == ["a", "b"]
+        assert all(isinstance(e, kenc.DenseEncoded) for _k, _n, e in rec)
+        assert jax_loaded is False

@@ -281,9 +281,10 @@ def _reg_run(store, ts: str, keys=("a", "b", "c")):
 
 def test_register_sweep_stages_on_the_main_thread(tmp_path, monkeypatch):
     """Every register and Knossos stage lands as a main-thread phase of
-    the sweep's trace.json; key "c" is routed to the CPU engine."""
-    from jepsen_tpu import cli
-    from jepsen_tpu.checker.knossos import dense
+    the sweep's trace.json; key "c" is routed to the CPU engine. The
+    load workers run in this process, where the patches below reach
+    them."""
+    from jepsen_tpu import cli, ingest
     from jepsen_tpu.checker.knossos import encode as kenc
     from jepsen_tpu.store import Store
 
@@ -306,8 +307,9 @@ def test_register_sweep_stages_on_the_main_thread(tmp_path, monkeypatch):
         return real_split(marked(hist))
 
     monkeypatch.setattr("jepsen_tpu.independent.subhistories", split)
-    monkeypatch.setattr(dense, "encode_dense_history",
-                        routed(dense.encode_dense_history))
+    monkeypatch.setattr(ingest, "_spawn_safe", lambda: False)
+    monkeypatch.setattr(kenc, "encode_dense_history",
+                        routed(kenc.encode_dense_history))
     monkeypatch.setattr(kenc, "encode_register_history",
                         routed(kenc.encode_register_history))
     monkeypatch.setenv("JEPSEN_TPU_BACKEND", "tpu")
