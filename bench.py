@@ -106,44 +106,6 @@ def bench_elle(n_dev: int, devices, reps: int) -> dict:
         "realtime_rate": timed(max(2, reps // 2), classify=False,
                                realtime=True),
     }
-    if accel and mesh is None:
-        # fused Pallas squaring vs the plain XLA matmul pipeline — the
-        # headline `value` above already uses whichever is the default,
-        # and `pallas_default` records which one that is so the faster
-        # formulation can be made (or kept) the default with evidence
-        try:
-            out["pallas_rate"] = timed(max(2, reps // 2), classify=False,
-                                       use_pallas=True, use_int8=False)
-        except Exception as e:  # lowering may fail on exotic hardware
-            out["pallas_rate"] = {"error": repr(e)[:200]}
-        out["xla_rate"] = timed(max(2, reps // 2), classify=False,
-                                use_pallas=False, use_int8=False)
-        # int8×int8→int32 squaring: exact for the boolean closure and
-        # ~2× the bf16 MXU throughput on v5e. Fusion (pallas) and
-        # arithmetic (int8) are orthogonal; the four-way race decides
-        # which JEPSEN_TPU_CLOSURE value becomes the production default
-        try:
-            out["int8_rate"] = timed(max(2, reps // 2), classify=False,
-                                     use_pallas=False, use_int8=True)
-        except Exception as e:
-            out["int8_rate"] = {"error": repr(e)[:200]}
-        try:
-            out["pallas_int8_rate"] = timed(
-                max(2, reps // 2), classify=False,
-                use_pallas=True, use_int8=True)
-        except Exception as e:
-            out["pallas_int8_rate"] = {"error": repr(e)[:200]}
-        from jepsen_tpu.checker.elle import kernels as K_
-        from jepsen_tpu.checker.elle import pallas_square
-        # which formulation the headline actually ran, plus each Pallas
-        # variant's lowering verdict (a variant can regress separately)
-        d_pallas, d_int8 = K_.resolve_formulation(single_device=True)
-        out["default_formulation"] = (
-            ("pallas" if d_pallas else "xla")
-            + ("-int8" if d_int8 else "-bf16"))
-        out["pallas_lowers"] = {
-            "bf16": bool(pallas_square.pallas_available()),
-            "int8": bool(pallas_square.pallas_available(int8=True))}
     return out
 
 
@@ -981,22 +943,15 @@ def bench_north_star(n_dev: int, devices) -> dict:
                 rounds_src = f"measured on {len(sample)} histories"
             except Exception as e:
                 rounds, rounds_src = 5.0, f"fallback: {e!r}"[:120]
-        # peak throughput of the formulation the sweep ACTUALLY ran:
-        # the auto default is the int8 closure (resolve_formulation).
-        # The peak comes from the device_kind-keyed table
-        # (kernels.device_peak); a CPU run has none, and no MFU.
-        # BENCH_PEAK_TFLOPS overrides.
-        use_pallas_f, use_int8_f = K_.resolve_formulation(
-            single_device=mesh is None)
+        # peak throughput of the closure's one (int8) formulation, from
+        # the device_kind-keyed table (kernels.device_peak); a CPU run
+        # has none, and no MFU. BENCH_PEAK_TFLOPS overrides.
         peak_row = K_.device_peak() if accel else None
         peak = float(os.environ.get(
             "BENCH_PEAK_TFLOPS",
-            (peak_row["int8_tops"] if use_int8_f
-             else peak_row["bf16_tflops"]) if peak_row else 0)) * 1e12
+            peak_row["int8_tops"] if peak_row else 0)) * 1e12
         mfu = (B * rounds * 2 * t_pad ** 3) / (t_check * peak * n_dev) \
             if accel else None
-        formulation = (("pallas" if use_pallas_f else "xla")
-                       + ("-int8" if use_int8_f else "-bf16"))
         # the cost observatory's sweep-level roofline: total bytes
         # accessed (per XLA's own cost model) over total measured
         # device seconds, against the peak-table HBM bandwidth. On a
@@ -1097,12 +1052,10 @@ def bench_north_star(n_dev: int, devices) -> dict:
             "invalid_found": n_bad,
             "closure_rounds": rounds,
             "rounds_source": rounds_src,
-            "mfu_formulation": formulation,
+            "mfu_formulation": K_.CLOSURE_FORMULATION,
             "mfu_measured": round(mfu, 4) if mfu is not None else None,
             "mfu_model": f"{rounds:g} rounds ({rounds_src}) x 2T^3 "
-                         f"{'int8' if use_int8_f else 'bf16'} ops, "
-                         f"peak {peak / 1e12:g} "
-                         f"{'TOPS' if use_int8_f else 'TFLOPS'}/chip",
+                         f"int8 ops, peak {peak / 1e12:g} TOPS/chip",
             # which peak the MFU denominator used (none on CPU)
             "peak": {"device_kind": peak_row["device_kind"],
                      "source": peak_row["source"],

@@ -7,7 +7,7 @@ record invocation/completion histories, and check those histories against
 consistency models.
 
 The differentiator is the analysis phase: histories are encoded as
-HBM-resident integer tensors and checked by JAX/Pallas kernels sharded across
+HBM-resident integer tensors and checked by JAX kernels sharded across
 a TPU mesh (Elle-style transactional anomaly search via MXU boolean
 transitive closure; Knossos-style linearizability via batched frontier
 search), so thousands of recorded runs can be verified in one batch.

@@ -1,7 +1,7 @@
 """Persistent AOT executable cache: zero XLA compiles on warm sweeps.
 
 A bucketed sweep's kernels are keyed by a tiny tuple — bucket shape,
-batch size, flag set, closure formulation, backend — yet every fresh
+batch size, flag set, backend — yet every fresh
 `analyze-store` process used to re-trace and re-compile each of them
 from scratch: tens of seconds of XLA time on the north-star shape
 before the first verdict, paid again on every repeat sweep over the
@@ -15,7 +15,7 @@ with two layers:
     (`jax.experimental.serialize_executable`), keyed by a digest of
     (jax/jaxlib version, backend platform + device count, jitted
     function name, input avals and shardings — a mesh's axis names
-    and sizes included — kernel flags, formulation), so a REPEAT sweep
+    and sizes included — kernel flags), so a REPEAT sweep
     in a fresh process deserializes instead of compiling.
 
 Single-device and mesh-sharded dispatches both resolve here: an

@@ -3,7 +3,7 @@
 The north-star compute path (SURVEY.md §3.3, BASELINE.json): encoded
 histories live in HBM as padded int32 tensors; dependency edges are built
 with dense scatters; cycle detection runs as boolean transitive closure by
-repeated matrix squaring — log2(T) bfloat16 matmuls that map straight onto
+repeated matrix squaring — log2(T) int8 matmuls that map straight onto
 the MXU — and anomaly classes fall out of closure/edge intersections:
 
   G0        some ww edge (u,v) with v→u in closure(ww)
@@ -18,7 +18,7 @@ and jit shardings; the single-device path passes identity. Realtime and
 process-order edges fold into the ww class (they strengthen cycles without
 adding anti-dependencies), masked to each history's live rows.
 
-All matmuls accumulate in float32 (`preferred_element_type`) from bf16
+All matmuls accumulate in int32 (`preferred_element_type`) from int8
 operands: entries are 0/1 so any nonzero dot-product term keeps the
 closure sound; magnitudes are re-thresholded to booleans every step.
 """
@@ -98,9 +98,9 @@ def stats_row(row, *, n_txns: int, t_pad: int) -> dict:
 #: an error, never a default: host CPUs and unknown chips have no peak,
 #: and their callers ask for none. Values are the published per-chip
 #: peaks: dense bf16 TFLOPS, int8
-#: TOPS (chips without an int8 fast path reuse the bf16 number — the
-#: closure is exact in either arithmetic, see _closure_batched), HBM
-#: bandwidth GB/s and capacity GiB.
+#: TOPS (chips without an int8 fast path reuse the bf16 number; the
+#: closure squares in int8, see _square), HBM bandwidth GB/s and
+#: capacity GiB.
 DEVICE_PEAKS: dict[str, dict] = {
     "tpu v2": {"bf16_tflops": 45.0, "int8_tops": 45.0,
                "hbm_gbps": 700.0, "hbm_gib": 16.0},
@@ -249,43 +249,6 @@ def fused_classify_enabled() -> bool:
     return gates.get("JEPSEN_TPU_FUSED_CLASSIFY")
 
 
-def resolve_formulation(use_pallas: bool | None = None,
-                        use_int8: bool | None = None, *,
-                        single_device: bool) -> tuple[bool, bool]:
-    """THE closure-formulation resolver, shared by every dispatch layer
-    (parallel.sharded_check_fn, check_encoded_batch, check_edge_batch)
-    so JEPSEN_TPU_CLOSURE reaches the production analyze-store paths,
-    not just the bench. Explicit arguments win; the env picks the
-    default: "bf16" / "int8" pin the XLA formulations, "pallas" /
-    "pallas-int8" opt into the fused ones. The auto default is the
-    XLA **int8** matmul pipeline (the closure is exact in either
-    arithmetic; int8 has twice the MXU rate of bf16 on v5e). Pallas
-    needs a single-device dispatch (sharded closures stay XLA for the
-    collectives) and a lowering probe that raises when the kernel does
-    not lower or miscomputes."""
-    from ... import gates
-
-    from . import pallas_square
-    # the registry validates the choice set and warns once on an
-    # unrecognized value, falling back to the auto default ("")
-    env = gates.get("JEPSEN_TPU_CLOSURE")
-    if use_int8 is None:
-        # auto default is int8: the boolean closure is exact in either
-        # arithmetic, and the MXU runs int8 at twice the bf16 rate.
-        # JEPSEN_TPU_CLOSURE=bf16 pins the old formulation.
-        use_int8 = env in ("int8", "pallas-int8") if env else True
-    if use_pallas is None:
-        if env in ("pallas", "pallas-int8") and single_device:
-            # explicit opt-in only; a kernel that does not lower raises
-            use_pallas = pallas_square.pallas_available(int8=use_int8)
-        else:
-            # auto default is the XLA matmul pipeline; fusion stays an
-            # explicit JEPSEN_TPU_CLOSURE=pallas[-int8] experiment until
-            # a chip cell shows it winning
-            use_pallas = False
-    return bool(use_pallas), bool(use_int8)
-
-
 def closure_steps(n_txns: int) -> int:
     """Squaring rounds needed for a T-node graph: path lengths double each
     round; (A|I)^(2^s) covers all simple paths once 2^s >= T."""
@@ -366,18 +329,10 @@ def _edges_one(appends: jnp.ndarray, reads: jnp.ndarray, n_keys: int,
     return ww, wr, rw
 
 
-def _closure_batched(m: jnp.ndarray, steps: int, constrain,
-                     use_pallas: bool = False,
-                     use_int8: bool = False) -> jnp.ndarray:
+def _closure_batched(m: jnp.ndarray, steps: int, constrain) -> jnp.ndarray:
     """Transitive closure of [B,T,T] boolean adjacencies via repeated
-    squaring; each squaring is one batched matmul on the MXU — bf16 by
-    default, or int8×int8→int32 with use_int8: the MXU's int8 path has
-    ~2× the bf16 throughput on v5e (394 TOPS vs 197 TFLOPS) and the
-    boolean closure is exact in either (non-negative terms, int32
-    accumulation never overflows below T=2^31). use_pallas composes
-    with use_int8 (fusion × arithmetic); the bench races all four
-    formulations and JEPSEN_TPU_CLOSURE (via resolve_formulation)
-    flips the dispatch default once hardware numbers justify it.
+    squaring; each squaring is one batched matmul on the MXU (see
+    `_square`).
 
     Runs to the fixpoint, not a fixed count: path lengths double each
     round, so convergence takes ~log2(graph diameter) rounds — for real
@@ -385,12 +340,6 @@ def _closure_batched(m: jnp.ndarray, steps: int, constrain,
     the early exit worth ~1.5x on the 5k-txn benchmark (the any()
     reduction per round is noise next to the matmul). `steps` stays the
     adversarial upper bound.
-
-    With use_pallas (unsharded TPU dispatches), the squaring runs as
-    the fused Pallas kernel (pallas_square.closure_square): the
-    cast/matmul/threshold pipeline stays in VMEM instead of making
-    bf16/f32 round-trips through HBM. Sharded dispatches keep the XLA
-    matmul so the compiler can insert the dp/mp collectives.
 
     Returns (closure, rounds): the round counter is the ACTUAL number
     of squarings executed before the fixpoint — closure_rounds_device
@@ -405,7 +354,7 @@ def _closure_batched(m: jnp.ndarray, steps: int, constrain,
 
     def body(carry):
         m, _, i = carry
-        m2 = _square(m, constrain, use_pallas, use_int8)
+        m2 = _square(m, constrain)
         return m2, jnp.any(m2 != m), i + 1
 
     m, _, i = jax.lax.while_loop(
@@ -413,31 +362,30 @@ def _closure_batched(m: jnp.ndarray, steps: int, constrain,
     return m, i
 
 
-def _square(m, constrain, use_pallas: bool, use_int8: bool):
+#: The closure's one formulation, as the costdb records and the
+#: planner's mode key name it (an on-disk field: older files carry the
+#: same string).
+CLOSURE_FORMULATION = "xla-int8"
+
+
+def _square(m, constrain):
     """ONE boolean matrix squaring — the loop body shared by
     `_closure_batched` and `_closure_batched_stats`, so the stats
     closure is bit-identical to the production one by construction
-    (the telemetry variant only adds bookkeeping around it)."""
-    if use_pallas:
-        from . import pallas_square
-        return pallas_square.closure_square(
-            m, interpret=pallas_square.INTERPRET, int8=use_int8)
-    if use_int8:
-        mb = constrain(m.astype(jnp.int8))
-        m2 = jax.lax.dot_general(
-            mb, mb, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.int32) > 0
-        return constrain(m2)
-    mb = constrain(m.astype(jnp.bfloat16))
+    (the telemetry variant only adds bookkeeping around it).
+
+    The matmul is int8×int8→int32: exact for a boolean closure
+    (non-negative 0/1 terms, and int32 accumulation cannot overflow
+    below T=2^31), and the MXU's int8 path has twice the bf16 rate on
+    v5e (394 TOPS against 197 TFLOPS)."""
+    mb = constrain(m.astype(jnp.int8))
     m2 = jax.lax.dot_general(
         mb, mb, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) > 0
+        preferred_element_type=jnp.int32) > 0
     return constrain(m2)
 
 
-def _closure_batched_stats(m: jnp.ndarray, steps: int, constrain,
-                           use_pallas: bool = False,
-                           use_int8: bool = False):
+def _closure_batched_stats(m: jnp.ndarray, steps: int, constrain):
     """`_closure_batched` with per-HISTORY search telemetry: the same
     squaring loop (same `_square` body, same batch-level fixpoint
     exit, so the returned closure — and every flag derived from it —
@@ -461,7 +409,7 @@ def _closure_batched_stats(m: jnp.ndarray, steps: int, constrain,
 
     def body(carry):
         m, _, i, rounds, cyc_round = carry
-        m2 = _square(m, constrain, use_pallas, use_int8)
+        m2 = _square(m, constrain)
         changed_h = jnp.any(m2 != m, axis=(1, 2))
         rounds = jnp.where(changed_h, i + 1, rounds)
         cyc_round = jnp.where((cyc_round < 0) & has_cycle(m2), i + 1,
@@ -531,8 +479,6 @@ def check_batched_impl(appends, reads, invoke_index, complete_index, process,
                        n_live, *, n_keys: int, max_pos: int, n_txns: int,
                        steps: int, classify: bool, realtime: bool,
                        process_order: bool, constrain,
-                       use_pallas: bool = False,
-                       use_int8: bool = False,
                        fused: bool = True,
                        with_stats: bool = False):
     """THE cycle-check kernel: packed [B,...] tensors -> [B] int32 flag
@@ -552,8 +498,7 @@ def check_batched_impl(appends, reads, invoke_index, complete_index, process,
     return classify_matrices_impl(
         ww, wr, rw, invoke_index, complete_index, process, n_live,
         steps=steps, classify=classify, realtime=realtime,
-        process_order=process_order, constrain=constrain,
-        use_pallas=use_pallas, use_int8=use_int8, fused=fused,
+        process_order=process_order, constrain=constrain, fused=fused,
         with_stats=with_stats, edge_counts=counts)
 
 
@@ -578,9 +523,7 @@ def _flags_from_closures(ww, wr, rw, c_ww, c_wwr, c_full, cycle,
 def classify_matrices_impl(ww, wr, rw, invoke_index, complete_index, process,
                            n_live, *, steps: int, classify: bool,
                            realtime: bool, process_order: bool,
-                           constrain, use_pallas: bool = False,
-                           use_int8: bool = False,
-                           fused: bool = True,
+                           constrain, fused: bool = True,
                            with_stats: bool = False,
                            edge_counts=None):
     """Closure + anomaly classification over explicit [B,T,T] boolean edge
@@ -640,10 +583,8 @@ def classify_matrices_impl(ww, wr, rw, invoke_index, complete_index, process,
         the SAME loop body, so the matrix (and every flag below) is
         bit-identical with the gate on or off."""
         if with_stats:
-            return _closure_batched_stats(m, steps, constrain,
-                                          use_pallas, use_int8)
-        c, _ = _closure_batched(m, steps, constrain, use_pallas,
-                                use_int8)
+            return _closure_batched_stats(m, steps, constrain)
+        c, _ = _closure_batched(m, steps, constrain)
         return c, None, None
 
     def result(flags, c_full, rounds, cyc_round):
@@ -679,10 +620,8 @@ def classify_matrices_impl(ww, wr, rw, invoke_index, complete_index, process,
 
         def _classify(ops):
             ww_, wr_, rw_, c_full_, cycle_ = ops
-            c_ww, _ = _closure_batched(ww_, steps, constrain,
-                                       use_pallas, use_int8)
-            c_wwr, _ = _closure_batched(c_ww | wr_, steps, constrain,
-                                        use_pallas, use_int8)
+            c_ww, _ = _closure_batched(ww_, steps, constrain)
+            c_wwr, _ = _closure_batched(c_ww | wr_, steps, constrain)
             return _flags_from_closures(ww_, wr_, rw_, c_ww, c_wwr,
                                         c_full_, cycle_, nI)
 
@@ -697,10 +636,8 @@ def classify_matrices_impl(ww, wr, rw, invoke_index, complete_index, process,
     # wider closure with the previous result is exact and each seeded
     # closure converges in the few rounds its NEW edge class adds,
     # instead of re-walking the whole graph three times.
-    c_ww, _ = _closure_batched(ww, steps, constrain, use_pallas,
-                               use_int8)
-    c_wwr, _ = _closure_batched(c_ww | wr, steps, constrain, use_pallas,
-                                use_int8)
+    c_ww, _ = _closure_batched(ww, steps, constrain)
+    c_wwr, _ = _closure_batched(c_ww | wr, steps, constrain)
     c_full, rounds, cyc_round = closure(c_wwr | rw)
     cycle = jnp.any(full & jnp.swapaxes(c_full, 1, 2) & nI, axis=(1, 2))
     return result(
@@ -714,14 +651,12 @@ def _identity(x):
 
 @functools.partial(jax.jit, static_argnames=(
     "n_keys", "max_pos", "n_txns", "steps", "classify", "realtime",
-    "process_order", "use_pallas", "use_int8", "fused", "with_stats"))
+    "process_order", "fused", "with_stats"))
 def check_batch_device(appends, reads, invoke_index, complete_index, process,
                        n_live, *, n_keys: int, max_pos: int, n_txns: int,
                        steps: int, classify: bool = True,
                        realtime: bool = False,
                        process_order: bool = False,
-                       use_pallas: bool = False,
-                       use_int8: bool = False,
                        fused: bool = True,
                        with_stats: bool = False):
     """Single-device jitted entry over a packed batch: [B] int32 flags
@@ -730,27 +665,23 @@ def check_batch_device(appends, reads, invoke_index, complete_index, process,
         appends, reads, invoke_index, complete_index, process, n_live,
         n_keys=n_keys, max_pos=max_pos, n_txns=n_txns, steps=steps,
         classify=classify, realtime=realtime, process_order=process_order,
-        constrain=_identity, use_pallas=use_pallas, use_int8=use_int8,
-        fused=fused, with_stats=with_stats)
+        constrain=_identity, fused=fused, with_stats=with_stats)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "steps", "classify", "realtime", "process_order", "use_pallas",
-    "use_int8", "fused", "with_stats"))
+    "steps", "classify", "realtime", "process_order", "fused",
+    "with_stats"))
 def classify_matrices_device(ww, wr, rw, invoke_index, complete_index,
                              process, n_live, *, steps: int,
                              classify: bool = True, realtime: bool = False,
                              process_order: bool = False,
-                             use_pallas: bool = False,
-                             use_int8: bool = False,
                              fused: bool = True,
                              with_stats: bool = False):
     """Jitted single-device entry over packed [B,T,T] edge matrices."""
     return classify_matrices_impl(
         ww, wr, rw, invoke_index, complete_index, process, n_live,
         steps=steps, classify=classify, realtime=realtime,
-        process_order=process_order, constrain=_identity,
-        use_pallas=use_pallas, use_int8=use_int8, fused=fused,
+        process_order=process_order, constrain=_identity, fused=fused,
         with_stats=with_stats)
 
 
@@ -829,16 +760,13 @@ def check_edge_batch(per_history: list[dict], realtime: bool = False,
         else:
             args = [jax.device_put(p[k], devices[0] if devices else None)
                     for k in names]
-    use_pallas, use_int8 = resolve_formulation(
-        single_device=len(devices) == 1)
     if fused is None:
         fused = fused_classify_enabled()
     with_stats = stats_out is not None
     with tr.phase_span("dispatch", B=len(per_history), T=p["T"]):
         out = classify_matrices_device(
             *args, steps=closure_steps(p["T"]), classify=classify,
-            realtime=realtime, process_order=process_order,
-            use_pallas=use_pallas, use_int8=use_int8, fused=fused,
+            realtime=realtime, process_order=process_order, fused=fused,
             with_stats=with_stats)
     tr.counter("buckets_dispatched").inc()
     flags, dev_stats = out if with_stats else (out, None)
@@ -939,14 +867,11 @@ def check_encoded_batch(encs: list[EncodedHistory],
             mesh, jax.sharding.PartitionSpec("dp"))
         args = [jax.device_put(a, sharding) for a in args]
 
-    use_pallas, use_int8 = resolve_formulation(
-        single_device=len(devices) == 1)
     with_stats = stats_out is not None
     out = check_batch_device(
         *args, n_keys=shape.n_keys, max_pos=shape.max_pos,
         n_txns=shape.n_txns, steps=closure_steps(shape.n_txns),
         classify=classify, realtime=realtime, process_order=process_order,
-        use_pallas=use_pallas, use_int8=use_int8,
         fused=fused_classify_enabled(), with_stats=with_stats)
     flags, dev_stats = out if with_stats else (out, None)
     if with_stats:

@@ -54,17 +54,15 @@ KINDS = ("bool", "int", "float", "str", "marker")
 class Gate:
     """One declared gate: name, kind, default, one doc line."""
 
-    __slots__ = ("name", "kind", "default", "doc", "choices")
+    __slots__ = ("name", "kind", "default", "doc")
 
-    def __init__(self, name: str, kind: str, default, doc: str,
-                 choices: tuple[str, ...] | None = None):
+    def __init__(self, name: str, kind: str, default, doc: str):
         assert kind in KINDS, kind
         assert name.startswith(PREFIX), name
         self.name = name
         self.kind = kind
         self.default = default
         self.doc = doc
-        self.choices = choices
 
     def parse(self, raw: str | None):
         """Typed value for a raw env string (None = unset)."""
@@ -91,12 +89,9 @@ class Gate:
                           self.name, raw, self.default)
                 return self.default
         # str — stripped: a trailing space from a shell export or CI
-        # YAML must not turn a valid choice into "unrecognized"
+        # YAML must not turn a valid value into an unknown one
         raw = raw.strip()
         if raw == "":
-            return self.default
-        if self.choices is not None and raw not in self.choices:
-            _warn_once(self.name, raw, self.choices)
             return self.default
         return raw
 
@@ -111,18 +106,6 @@ class Gate:
         return f"`{self.default}`"
 
 
-_warned: set[str] = set()
-
-
-def _warn_once(name: str, raw: str, choices) -> None:
-    if name in _warned:
-        return
-    _warned.add(name)
-    want = "|".join(c for c in choices if c)
-    log.warning("unrecognized %s=%r (want %s); using the default",
-                name, raw, want)
-
-
 # ---------------------------------------------------------------------------
 # The registry. Ordering is the README table ordering.
 # ---------------------------------------------------------------------------
@@ -130,10 +113,9 @@ def _warn_once(name: str, raw: str, choices) -> None:
 GATES: dict[str, Gate] = {}
 
 
-def _g(name: str, kind: str, default, doc: str,
-       choices: tuple[str, ...] | None = None) -> None:
+def _g(name: str, kind: str, default, doc: str) -> None:
     assert name not in GATES, f"duplicate gate {name}"
-    GATES[name] = Gate(name, kind, default, doc, choices)
+    GATES[name] = Gate(name, kind, default, doc)
 
 
 # -- observability ----------------------------------------------------------
@@ -201,10 +183,6 @@ _g("JEPSEN_TPU_PLATFORM", "str", None,
    "analysis devices come from this jax platform (`cpu`, `tpu`) "
    "instead of the default backend; also selects the real-hardware "
    "test tier")
-_g("JEPSEN_TPU_CLOSURE", "str", "",
-   "closure formulation: `bf16`|`int8`|`pallas`|`pallas-int8` "
-   "(auto default is the XLA int8 matmul pipeline)",
-   choices=("", "bf16", "int8", "pallas", "pallas-int8"))
 _g("JEPSEN_TPU_FUSED_CLASSIFY", "bool", True,
    "`0`: detect-then-classify two-pass instead of the fused kernel")
 _g("JEPSEN_TPU_FRONTIER", "int", 512,

@@ -8,10 +8,9 @@ and a Python `if` on a tracer either crashes (ConcretizationError) or
 — worse — got hoisted to trace time and bakes one branch into the
 compiled kernel. These rules police the hazards lexically: inside
 `@jax.jit`-decorated functions everywhere, plus module-wide in the
-kernel modules (`checker/elle/kernels.py`, `checker/elle/
-pallas_square.py`, `checker/knossos/`), and `block_until_ready`
-anywhere outside the sanctioned watchdog wrappers (`parallel/`,
-`supervisor.py`).
+kernel modules (`checker/elle/kernels.py`, `checker/knossos/`), and
+`block_until_ready` anywhere outside the sanctioned watchdog wrappers
+(`parallel/`, `supervisor.py`).
 """
 
 from __future__ import annotations
@@ -22,8 +21,7 @@ from typing import Iterator
 from . import Finding, ModuleCtx, ModuleRule, const_str, dotted
 
 #: Modules whose ENTIRE body is treated as kernel code for JT-JAX-001.
-_KERNEL_MODULES = ("jepsen_tpu/checker/elle/kernels.py",
-                   "jepsen_tpu/checker/elle/pallas_square.py")
+_KERNEL_MODULES = ("jepsen_tpu/checker/elle/kernels.py",)
 _KERNEL_PREFIXES = ("jepsen_tpu/checker/knossos/",)
 
 #: Modules sanctioned to call block_until_ready (the watchdog wrappers).

@@ -11,9 +11,9 @@ per-(kernel, geometry) cost model to train on. This module closes
 that loop in three parts, all behind `JEPSEN_TPU_COSTDB` (default
 off ⇒ zero new files, <1µs per dispatch):
 
-  * **capture** — `observe()` runs once per (kernel flags +
-    formulation + bucket geometry) key: the compiled executable's
-    `cost_analysis()` (flops, bytes accessed, transcendentals) and
+  * **capture** — `observe()` runs once per (kernel flags + bucket
+    geometry) key: the compiled executable's `cost_analysis()`
+    (flops, bytes accessed, transcendentals) and
     `memory_analysis()` (argument/output/temp/generated-code bytes),
     called from `aot.compiled_for` for every bucket dispatch, and —
     with the AOT cache off — from
@@ -59,11 +59,9 @@ log = logging.getLogger(__name__)
 #: Layout of the dispatch cost key — MUST match
 #: `parallel.residency.ExecutableResidency.dispatch_key` (pinned by
 #: tests/test_costdb.py so the two can't drift): (classify, realtime,
-#: process_order, fused, use_pallas, use_int8, donate, n_keys,
-#: max_pos, n_txns).
+#: process_order, fused, donate, n_keys, max_pos, n_txns).
 _KEY_FIELDS = ("classify", "realtime", "process_order", "fused",
-               "use_pallas", "use_int8", "donate", "n_keys",
-               "max_pos", "n_txns")
+               "donate", "n_keys", "max_pos", "n_txns")
 
 _LOCK = threading.Lock()
 
@@ -102,15 +100,13 @@ def reset() -> None:
         _last_mem_poll = 0.0
 
 
-def dispatch_cost_key(kw: dict, shape, single_device: bool,
-                      donate: bool) -> tuple:
+def dispatch_cost_key(kw: dict, shape, donate: bool) -> tuple:
     """THE cost key for one bucket dispatch: it IS
     `ExecutableResidency.dispatch_key`, so the AOT cache and the
     costdb key the same executable identically, mesh-sharded or
     not."""
     from ..parallel.residency import ExecutableResidency
-    return ExecutableResidency.dispatch_key(kw, shape, donate,
-                                            single_device=single_device)
+    return ExecutableResidency.dispatch_key(kw, shape, donate)
 
 
 def _cost_dict(obj) -> dict | None:
@@ -175,6 +171,7 @@ def observe(key_parts: tuple, args, obj, source: str) -> None:
     if not enabled():
         return
     try:
+        from ..checker.elle import kernels as K
         B = int(args[0].shape[0])
         key = (tuple(key_parts), B)
         with _LOCK:
@@ -192,9 +189,9 @@ def observe(key_parts: tuple, args, obj, source: str) -> None:
         platform, device_kind = _backend_info()
         geometry = {
             "B": B,
-            "n_txns": int(key_parts[9]),
-            "n_keys": int(key_parts[7]),
-            "max_pos": int(key_parts[8]),
+            "n_txns": int(key_parts[7]),
+            "n_keys": int(key_parts[5]),
+            "max_pos": int(key_parts[6]),
             "n_appends": int(args[0].shape[1]),
             "n_reads": int(args[1].shape[1]),
         }
@@ -203,9 +200,8 @@ def observe(key_parts: tuple, args, obj, source: str) -> None:
             "key_parts": tuple(key_parts),
             "kernel": {f: key_parts[i] for i, f in
                        enumerate(_KEY_FIELDS[:4])},
-            "formulation": (("pallas" if key_parts[4] else "xla")
-                            + ("-int8" if key_parts[5] else "-bf16")),
-            "donated": bool(key_parts[6]),
+            "formulation": K.CLOSURE_FORMULATION,
+            "donated": bool(key_parts[4]),
             "geometry": geometry,
             "backend": platform,
             "device_kind": device_kind,
@@ -240,8 +236,8 @@ def _modeled_bytes(rec: dict, args) -> int:
     return n
 
 
-def begin_dispatch(flags, kw: dict, shape, single_device: bool,
-                   donate: bool, args, tr=None) -> None:
+def begin_dispatch(flags, kw: dict, shape, donate: bool, args,
+                   tr=None) -> None:
     """Open one dispatch's measured window: remember which record the
     flags array (the live device result) belongs to, add its modeled
     HBM to the in-flight gauge, and publish the residency gauges.
@@ -250,7 +246,7 @@ def begin_dispatch(flags, kw: dict, shape, single_device: bool,
         return
     try:
         global _inflight_bytes
-        key = (dispatch_cost_key(kw, shape, single_device, donate),
+        key = (dispatch_cost_key(kw, shape, donate),
                int(args[0].shape[0]))
         with _LOCK:
             rec = _records.get(key)
@@ -370,11 +366,9 @@ def _finalize(rec: dict) -> dict:
         if isinstance(cost.get("flops"), (int, float)):
             achieved["flops_per_sec"] = cost["flops"] * per_sec
             if peak is not None:
-                peak_ops = (peak["int8_tops"] if "int8" in
-                            (rec.get("formulation") or "")
-                            else peak["bf16_tflops"]) * 1e12
                 roofline["flops_utilization"] = round(
-                    achieved["flops_per_sec"] / peak_ops, 6)
+                    achieved["flops_per_sec"]
+                    / (peak["int8_tops"] * 1e12), 6)
         if isinstance(cost.get("bytes_accessed"), (int, float)):
             achieved["bytes_per_sec"] = cost["bytes_accessed"] * per_sec
             if peak is not None:

@@ -107,38 +107,19 @@ def init_distributed() -> bool:
 def sharded_check_fn(mesh: Mesh | None, shape: K.BatchShape, *,
                      classify: bool = True, realtime: bool = False,
                      process_order: bool = False,
-                     use_pallas: bool | None = None,
-                     use_int8: bool | None = None,
                      fused: bool | None = None,
                      donate: bool = False,
                      with_stats: bool = False):
     """Build a jitted batched checker around kernels.check_batched_impl.
     With a mesh, inputs are expected sharded over 'dp' and the closure
     matrices are constrained to P('dp', None, 'mp'); without one, it's
-    a plain single-device jit. The closure squaring defaults to the
-    XLA matmul pipeline on every backend — the formulation the v5e
-    hardware race picked (the fused Pallas kernel measured ~2.7×
-    slower at the 5000-txn headline shape; `JEPSEN_TPU_CLOSURE=
-    pallas[-int8]` re-enables it as an experiment, and benchmarks
-    pass explicit bools to race the formulations). use_int8 switches
-    the squaring dots to int8×int8→int32 — exact for the boolean
-    closure — and composes with use_pallas (the VMEM fusion and the
-    arithmetic are orthogonal levers). Mesh dispatches always take
-    the XLA formulation so the compiler can insert collectives; the
-    compiled executable carries them, so bucket dispatches resolve it
+    a plain single-device jit. A mesh's compiled executable carries
+    the collectives XLA inserted, so bucket dispatches resolve it
     through the AOT executable map like single-device ones
-    (residency.ExecutableResidency). Explicit arguments win over the
-    env. Memoized per (mesh, shape, flags): a repeat call returns the
-    same jitted wrapper, and an evicted one costs a rebuild, never a
-    re-trace, on the bucket path."""
-    if use_pallas and mesh is not None:
-        # the Pallas squaring path bypasses the P('dp',None,'mp')
-        # sharding constraint and would silently degrade sharded
-        # layouts; sharded dispatch always uses the XLA formulation
-        raise ValueError("use_pallas=True is single-device only: "
-                         "sharded dispatch uses the XLA closure path")
-    use_pallas, use_int8 = K.resolve_formulation(
-        use_pallas, use_int8, single_device=mesh is None)
+    (residency.ExecutableResidency). Memoized per (mesh, shape,
+    flags): a repeat call returns the same jitted wrapper, and an
+    evicted one costs a rebuild, never a re-trace, on the bucket
+    path."""
     if fused is None:
         fused = K.fused_classify_enabled()
     # fused only exists in classify mode; normalize so detect-mode
@@ -149,8 +130,8 @@ def sharded_check_fn(mesh: Mesh | None, shape: K.BatchShape, *,
     # the flag can't split the compile cache for sharded dispatches
     donate = bool(donate) and mesh is None
     return _sharded_check_fn_cached(mesh, shape, classify, realtime,
-                                    process_order, use_pallas, use_int8,
-                                    fused, donate, bool(with_stats))
+                                    process_order, fused, donate,
+                                    bool(with_stats))
 
 
 # Executable residency + donated-slot ownership live in
@@ -167,8 +148,6 @@ _slots = residency.DeviceSlots()
 def _sharded_check_fn_cached(mesh: Mesh | None, shape: K.BatchShape,
                              classify: bool, realtime: bool,
                              process_order: bool,
-                             use_pallas: bool = False,
-                             use_int8: bool = False,
                              fused: bool = False,
                              donate: bool = False,
                              with_stats: bool = False):
@@ -186,8 +165,7 @@ def _sharded_check_fn_cached(mesh: Mesh | None, shape: K.BatchShape,
         K.check_batched_impl, n_keys=shape.n_keys, max_pos=shape.max_pos,
         n_txns=shape.n_txns, steps=K.closure_steps(shape.n_txns),
         classify=classify, realtime=realtime, process_order=process_order,
-        constrain=constrain, use_pallas=use_pallas, use_int8=use_int8,
-        fused=fused, with_stats=with_stats)
+        constrain=constrain, fused=fused, with_stats=with_stats)
     # JAX names the compiled module after the function it traces, and a
     # bare partial reads as `<unknown>` (`jit__unknown` in a device
     # trace, cached and reloaded under that name). The executable's
@@ -615,12 +593,11 @@ def _dispatch_fn(bucket_mesh, shape: K.BatchShape, kw: dict, args,
     """The callable for one bucket dispatch, mesh-sharded or not: with
     the AOT cache on, a persistent compiled executable
     (residency.ExecutableResidency over jepsen_tpu.aot) keyed by the
-    input avals and shardings + kernel flags + formulation, so a
+    input avals and shardings + kernel flags + geometry, so a
     repeat dispatch never re-traces and a repeat sweep pays zero XLA
     compiles; else the jitted check fn."""
     fn = sharded_check_fn(bucket_mesh, shape, donate=donate, **kw)
-    return _residency.dispatch_fn(fn, bucket_mesh, shape, kw, args,
-                                  donate)
+    return _residency.dispatch_fn(fn, shape, kw, args, donate)
 
 
 def _donate_active(bucket_mesh) -> bool:
@@ -656,8 +633,7 @@ def _sync_check(encs, idx: list, mesh, budget_cells: int, kw: dict,
     try:
         t_disp = time.perf_counter()
         flags = fn(*args)
-        obs_device.begin_dispatch(flags, kw, shape, bucket_mesh is None,
-                                  donate, args, tr)
+        obs_device.begin_dispatch(flags, kw, shape, donate, args, tr)
         try:
             arr = np.asarray(_block_flags(flags, tr))
         except BaseException:
@@ -897,7 +873,6 @@ def check_bucketed_async(encs: Sequence, mesh: Mesh | None = None, *,
                                   (dev_stats, shape)
                                   if dev_stats is not None else None))
                     obs_device.begin_dispatch(flags, kw, shape,
-                                              bucket_mesh is None,
                                               donate, args, tr)
                     inflight.append(len(parts) - 1)
                     tr.counter("buckets_dispatched").inc()

@@ -13,8 +13,8 @@ bookkeeping, so the two non-scheduling concerns live here:
 
   * `ExecutableResidency` — resolves the callable for one dispatch,
     mesh-sharded or single-device alike: the persistent AOT-compiled
-    executable (jepsen_tpu.aot), keyed by kernel flags + resolved
-    formulation + batch geometry + input shardings (a mesh's axis
+    executable (jepsen_tpu.aot), keyed by kernel flags + batch
+    geometry + input shardings (a mesh's axis
     names and sizes among them), so a warm owner pays zero XLA
     compiles — and zero re-traces — however many dispatch loops it
     runs. A sharded executable carries the collectives XLA inserted
@@ -45,8 +45,7 @@ class ExecutableResidency:
     stable key, so callers ask for "the callable for this dispatch"
     and never learn how executables are stored."""
 
-    def dispatch_fn(self, fn, bucket_mesh, shape, kw: dict, args,
-                    donate: bool):
+    def dispatch_fn(self, fn, shape, kw: dict, args, donate: bool):
         """The callable for one bucket dispatch: the persistent compiled
         executable for `fn` over `args` when the AOT cache is on, found
         by input avals and shardings before anything traces — so a
@@ -56,8 +55,7 @@ class ExecutableResidency:
         feeds the device cost observatory (obs.device,
         JEPSEN_TPU_COSTDB; the compiled path captures inside
         aot.compiled_for)."""
-        key = self.dispatch_key(kw, shape, donate,
-                                single_device=bucket_mesh is None)
+        key = self.dispatch_key(kw, shape, donate)
         if not self._aot_enabled():
             from ..obs import device as device_obs
             device_obs.observe(key, args, fn, source="lowered")
@@ -78,11 +76,9 @@ class ExecutableResidency:
         return aot.resident_count()
 
     @staticmethod
-    def dispatch_key(kw: dict, shape, donate: bool, *,
-                     single_device: bool = True) -> tuple:
+    def dispatch_key(kw: dict, shape, donate: bool) -> tuple:
         """The stable half of the AOT cache key for one dispatch: kernel
-        flags + the closure formulation RESOLVED for the dispatch's kind
-        (a mesh never takes Pallas) + batch geometry (aot itself adds
+        flags + batch geometry (aot itself adds
         input avals and shardings — a mesh's axis names and sizes with
         them — backend topology and jax/jaxlib versions). A
         kernel-stats dispatch (JEPSEN_TPU_KERNEL_STATS) returns a
@@ -90,12 +86,8 @@ class ExecutableResidency:
         marker is APPENDED only when the flag is on, so the gate-off
         key (and every cached executable keyed under it) is
         byte-identical to before."""
-        from ..checker.elle import kernels as K
-        use_pallas, use_int8 = K.resolve_formulation(
-            single_device=single_device)
         return (kw.get("classify", True), kw.get("realtime", False),
-                kw.get("process_order", False), kw.get("fused"),
-                use_pallas, use_int8, donate,
+                kw.get("process_order", False), kw.get("fused"), donate,
                 shape.n_keys, shape.max_pos, shape.n_txns) \
             + (("stats",) if kw.get("with_stats") else ())
 

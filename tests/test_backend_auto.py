@@ -84,37 +84,6 @@ def test_default_devices_never_substitutes_cpu(monkeypatch):
     assert devices.accelerator_available() is True
 
 
-def test_pallas_available_raises_on_failing_lowering(monkeypatch):
-    from jepsen_tpu.checker.elle import pallas_square
-
-    def no_lowering(*a, **kw):
-        raise RuntimeError("Mosaic refused the kernel")
-
-    monkeypatch.setattr(devices, "default_devices",
-                        lambda: [_FakeDevice("tpu")])
-    monkeypatch.setattr(pallas_square, "_works", {})
-    monkeypatch.setattr(pallas_square, "closure_square", no_lowering)
-    with pytest.raises(RuntimeError, match="Mosaic refused"):
-        pallas_square.pallas_available()
-
-
-def test_pallas_available_raises_on_miscomputed_probe(monkeypatch):
-    import jax.numpy as jnp
-    from jepsen_tpu.checker.elle import pallas_square
-    monkeypatch.setattr(devices, "default_devices",
-                        lambda: [_FakeDevice("tpu")])
-    monkeypatch.setattr(pallas_square, "_works", {})
-    monkeypatch.setattr(pallas_square, "closure_square",
-                        lambda m, int8=False: jnp.zeros_like(m))
-    with pytest.raises(RuntimeError, match="miscomputed"):
-        pallas_square.pallas_available(int8=True)
-
-
-def test_pallas_unavailable_off_tpu():
-    from jepsen_tpu.checker.elle import pallas_square
-    assert pallas_square.pallas_available() is False
-
-
 def test_device_peak_raises_on_unknown_kind():
     from jepsen_tpu.checker.elle import kernels as K
     with pytest.raises(KeyError):

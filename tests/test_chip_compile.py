@@ -13,7 +13,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 
 from jepsen_tpu import parallel
 from jepsen_tpu.checker.elle import kernels as K
-from jepsen_tpu.checker.elle import pallas_square, synth
+from jepsen_tpu.checker.elle import synth
 from jepsen_tpu.checker.knossos import dense
 from jepsen_tpu.checker.knossos import synth as ksynth
 
@@ -58,26 +58,32 @@ def _device_bytes(compiled) -> int:
             + m.output_size_in_bytes)
 
 
-@pytest.mark.parametrize("int8", [True, False], ids=["int8", "bf16"])
 @pytest.mark.parametrize("classify", [False, True],
                          ids=["detect", "fused-classify"])
-def test_north_star_check_compiles(one_chip, north_enc, int8, classify):
+def test_north_star_check_compiles(one_chip, north_enc, classify):
     shape = K.BatchShape.plan([north_enc] * B_NORTH)
     assert shape.n_txns == 5120 and shape.max_pos == 80
     fn = parallel.sharded_check_fn(None, shape, classify=classify,
-                                   use_int8=int8, use_pallas=False,
                                    fused=True)
     compiled = fn.lower(*_check_args(shape, B_NORTH, one_chip)).compile()
     # one north-star bucket fits one chip's HBM; two do not
     assert _device_bytes(compiled) < HBM_BYTES
 
 
-@pytest.mark.parametrize("int8", [True, False], ids=["int8", "bf16"])
-def test_closure_square_compiles(one_chip, int8):
-    m = jax.ShapeDtypeStruct((B_NORTH, 5120, 5120), jnp.bool_,
-                             sharding=one_chip)
-    compiled = pallas_square.closure_square.lower(m, int8=int8).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def test_wr_bucket_compiles(one_chip):
+    """The rw-register sweep's bucket: 5 histories of host-built
+    [T,T] edge matrices at T=4992, classified with the fused kernel
+    (check_edge_batch's defaults), compile for one chip and fit it."""
+    B, T = 5, 4992
+
+    def s(dtype, *dims):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    args = (s(jnp.bool_, B, T, T), s(jnp.bool_, B, T, T),
+            s(jnp.bool_, B, T, T), s(jnp.int32, B, T),
+            s(jnp.int32, B, T), s(jnp.int32, B, T), s(jnp.int32, B))
+    compiled = K.classify_matrices_device.lower(
+        *args, steps=K.closure_steps(T), classify=True,
+        fused=True).compile()
     assert _device_bytes(compiled) < HBM_BYTES
 
 

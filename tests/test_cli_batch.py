@@ -560,20 +560,6 @@ def test_stored_fallback_sidecar_records_validity(tmp_path, capsys):
     capsys.readouterr()
 
 
-def test_sharded_check_fn_rejects_pallas_on_mesh():
-    """ADVICE r3: the Pallas squaring path would silently drop the
-    dp/mp sharding constraint; an explicit use_pallas=True with a mesh
-    must be a loud error, not a degraded layout."""
-    import pytest as _pytest
-
-    from jepsen_tpu import parallel
-    from jepsen_tpu.checker.elle import synth
-    mesh = parallel.make_mesh()
-    shape = synth.synth_valid_batch(B=2, T=32, K=4, seed=0)["shape"]
-    with _pytest.raises(ValueError, match="single-device"):
-        parallel.sharded_check_fn(mesh, shape, use_pallas=True)
-
-
 def test_init_distributed_gating(monkeypatch):
     from jepsen_tpu import parallel
     monkeypatch.delenv("JAX_COORDINATOR_ADDRESS", raising=False)

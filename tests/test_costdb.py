@@ -86,18 +86,17 @@ class TestPeakTable:
               "process_order": False, "fused": True}
         key = ExecutableResidency.dispatch_key(kw, shape, donate=True)
         assert len(key) == len(device_obs._KEY_FIELDS)
-        assert key[0] is True and key[6] is True          # classify, donate
-        assert key[7] == 16 and key[8] == 24 and key[9] == 128
-        assert key == device_obs.dispatch_cost_key(
-            kw, shape, single_device=True, donate=True)
-        # a mesh dispatch keys the same layout (its mesh rides the AOT
-        # fingerprint's shardings), and the costdb joins it the same way
-        mesh_key = ExecutableResidency.dispatch_key(
-            kw, shape, donate=False, single_device=False)
+        assert key[0] is True and key[4] is True          # classify, donate
+        assert key[5] == 16 and key[6] == 24 and key[7] == 128
+        assert key == device_obs.dispatch_cost_key(kw, shape, donate=True)
+        # a mesh dispatch (donation normalized off) keys the same
+        # layout (its mesh rides the AOT fingerprint's shardings), and
+        # the costdb joins it the same way
+        mesh_key = ExecutableResidency.dispatch_key(kw, shape, donate=False)
         assert len(mesh_key) == len(device_obs._KEY_FIELDS)
-        assert mesh_key[6] is False and mesh_key[7:] == key[7:]
+        assert mesh_key[4] is False and mesh_key[5:] == key[5:]
         assert mesh_key == device_obs.dispatch_cost_key(
-            kw, shape, single_device=False, donate=False)
+            kw, shape, donate=False)
 
 
 # ---------------------------------------------------------------------------
@@ -117,8 +116,7 @@ class TestCapture:
         assert r["v"] == 1
         assert set(r["kernel"]) == {"classify", "realtime",
                                     "process_order", "fused"}
-        assert r["formulation"] in ("xla-int8", "xla-bf16",
-                                    "pallas-int8", "pallas-bf16")
+        assert r["formulation"] == "xla-int8"
         g = r["geometry"]
         assert g["B"] >= len(encs) and g["n_txns"] % 128 == 0
         assert set(g) == {"B", "n_txns", "n_keys", "max_pos",
@@ -306,8 +304,8 @@ class TestAnalyzeStore:
         n = 20_000
         t0 = time.perf_counter()
         for _ in range(n):
-            device_obs.begin_dispatch(sentinel, {}, None, True, False,
-                                      None, None)
+            device_obs.begin_dispatch(sentinel, {}, None, False, None,
+                                      None)
             device_obs.close_dispatch(sentinel, t0, 1, None)
         per_pair = (time.perf_counter() - t0) / n
         assert per_pair < 5e-6, f"{per_pair * 1e6:.2f}µs per disabled pair"

@@ -82,11 +82,14 @@ def test_fuzz_append_parity():
 
 
 def test_fuzz_int8_closure_parity():
-    """The int8 squaring must agree with bf16 (and so with the CPU
-    oracle) on randomly corrupted batches — the exactness argument
-    (non-negative terms, int32 accumulation) fuzz-checked end to end."""
+    """The int8 squaring, run batched over 3 histories at a time, must
+    give each history the CPU oracle's anomalies on randomly corrupted
+    batches — the exactness argument (non-negative terms, int32
+    accumulation) fuzz-checked end to end, in detect and classify
+    mode."""
     import numpy as np
 
+    from jepsen_tpu.checker.elle import cycle_anomalies_cpu
     from jepsen_tpu.checker.elle import encode as elle_encode
     from jepsen_tpu.checker.elle import kernels as K
     rng = random.Random(31)
@@ -96,6 +99,7 @@ def test_fuzz_int8_closure_parity():
                                      rng.choice([1, 5]))
                  for _ in range(3)]
         encs = [elle_encode.encode_history(h) for h in hists]
+        want = [sorted(cycle_anomalies_cpu(e)) for e in encs]
         packed = K.pack_batch(encs)
         sh = packed["shape"]
         names = ("appends", "reads", "invoke_index", "complete_index",
@@ -103,12 +107,14 @@ def test_fuzz_int8_closure_parity():
         args = tuple(packed[k] for k in names)
         kw = dict(n_keys=sh.n_keys, max_pos=sh.max_pos,
                   n_txns=sh.n_txns, steps=K.closure_steps(sh.n_txns))
-        for classify in (False, True):
-            bf16 = np.asarray(K.check_batch_device(
-                *args, classify=classify, use_int8=False, **kw))
-            i8 = np.asarray(K.check_batch_device(
-                *args, classify=classify, use_int8=True, **kw))
-            assert bf16.tolist() == i8.tolist(), (trial, classify)
+        classified = np.asarray(K.check_batch_device(
+            *args, classify=True, **kw)).tolist()
+        assert [sorted(K.flags_to_names(w)) for w in classified] \
+            == want, (trial, classified, want)
+        detected = np.asarray(K.check_batch_device(
+            *args, classify=False, **kw)).tolist()
+        assert [bool(w >> K.CYCLE & 1) for w in detected] \
+            == [bool(a) for a in want], (trial, detected, want)
 
 
 def test_fuzz_wr_parity():

@@ -38,7 +38,6 @@ ALL_GATES = [
     "JEPSEN_TPU_KERNEL_STATS_SAMPLE",
     "JEPSEN_TPU_BACKEND",
     "JEPSEN_TPU_PLATFORM",
-    "JEPSEN_TPU_CLOSURE",
     "JEPSEN_TPU_FUSED_CLASSIFY",
     "JEPSEN_TPU_FRONTIER",
     "JEPSEN_TPU_NATIVE_INGEST",
@@ -125,18 +124,9 @@ def test_float_malformed_falls_back(monkeypatch):
     assert gates.get("JEPSEN_TPU_MESH_WAIT_S") == 7.5
 
 
-def test_str_choices_reject_unknown(monkeypatch):
-    monkeypatch.setenv("JEPSEN_TPU_CLOSURE", "int7")
-    assert gates.get("JEPSEN_TPU_CLOSURE") == ""   # the auto default
-    monkeypatch.setenv("JEPSEN_TPU_CLOSURE", "pallas-int8")
-    assert gates.get("JEPSEN_TPU_CLOSURE") == "pallas-int8"
-
-
 def test_str_values_are_stripped(monkeypatch):
     # a trailing space from a shell export or CI YAML must not turn a
-    # valid choice into "unrecognized" (the old read .strip()ed too)
-    monkeypatch.setenv("JEPSEN_TPU_CLOSURE", " pallas ")
-    assert gates.get("JEPSEN_TPU_CLOSURE") == "pallas"
+    # valid value into an unknown one (the old read .strip()ed too)
     monkeypatch.setenv("JEPSEN_TPU_BACKEND", " cpu ")
     assert gates.get("JEPSEN_TPU_BACKEND") == "cpu"
     monkeypatch.setenv("JEPSEN_TPU_BACKEND", "   ")

@@ -229,18 +229,13 @@ class TestGateOffFree:
         kw = {"classify": True, "realtime": False,
               "process_order": False, "fused": True}
         off = ExecutableResidency.dispatch_key(kw, shape, donate=True)
-        assert off == (True, False, False, True, False, True, True,
-                       8, 8, 128)
+        assert off == (True, False, False, True, True, 8, 8, 128)
         on = ExecutableResidency.dispatch_key(
             {**kw, "with_stats": True}, shape, donate=True)
         assert on == off + ("stats",)
-        # the costdb mirrors the same rule on the mesh branch
+        # the costdb mirrors the same rule on the (undonated) mesh key
         assert device_obs.dispatch_cost_key(
-            {**kw, "with_stats": True}, shape, False, False)[-1] \
-            == "stats"
-        # naming the single-device kind explicitly changes nothing
-        assert ExecutableResidency.dispatch_key(
-            kw, shape, donate=True, single_device=True) == off
+            {**kw, "with_stats": True}, shape, False)[-1] == "stats"
 
     def test_single_device_fingerprint_golden(self):
         """A single-device dispatch's AOT fingerprint is byte-identical
@@ -262,8 +257,8 @@ class TestGateOffFree:
                                (2, 128), (2,)])
         assert aot._sharding_key(args[0]) == "SingleDeviceSharding[0]"
         assert aot._fingerprint(fn, args, key) == (
-            "b7b4d66cd0bfb0882abb2bae02daaa11"
-            "513d31fecdbdc3a16502a21738749a0d")
+            "2bd076c94f6e408e12d935fdcb6f7496"
+            "bd31e000f41ac82c173fd003e8675957")
 
     def test_gate_off_overhead_sub_microsecond(self, monkeypatch):
         """The added per-record code path with the gate off is one

@@ -95,52 +95,25 @@ def test_closure_rounds_measured_on_device():
     assert r1 == r2   # deterministic on the same batch
 
 
-def test_pallas_and_xla_formulations_agree_on_device():
-    """Both squaring formulations must produce identical flags on the
-    chip — the precondition for the bench's pallas-vs-xla comparison
-    (and for making either the default)."""
-    from jepsen_tpu import parallel
-    from jepsen_tpu.checker.elle import pallas_square, synth
-    if not pallas_square.pallas_available():
-        pytest.skip("pallas lowering unavailable on this backend")
-    import jax
-    import numpy as np
-    batch = synth.synth_valid_batch(B=4, T=256, K=16, seed=2)
-    batch = synth.inject_g1c(batch, np.asarray([1]), 16)
-    shape = batch["shape"]
-    args = parallel.shard_batch(None, batch)
-    f_p = parallel.sharded_check_fn(None, shape, use_pallas=True,
-                                    use_int8=False)
-    f_x = parallel.sharded_check_fn(None, shape, use_pallas=False,
-                                    use_int8=False)
-    f_p8 = parallel.sharded_check_fn(None, shape, use_pallas=True,
-                                     use_int8=True)
-    fp = np.asarray(jax.block_until_ready(f_p(*args)))
-    fx = np.asarray(jax.block_until_ready(f_x(*args)))
-    fp8 = np.asarray(jax.block_until_ready(f_p8(*args)))
-    assert fp.tolist() == fx.tolist() == fp8.tolist()
-    assert fx[1] & (1 << elle_kernels.G1C)
-
-
 def test_int8_formulation_agrees_on_device():
-    """int8×int8→int32 squaring must match bf16 on the real MXU — the
-    precondition for flipping JEPSEN_TPU_CLOSURE=int8 when the bench
-    shows the ~2× int8 path winning."""
+    """The int8×int8→int32 squaring on the real MXU must give each
+    history of a batch the CPU oracle's anomalies."""
     from jepsen_tpu import parallel
     import jax
     import numpy as np
-    from jepsen_tpu.checker.elle import synth
-    batch = synth.synth_valid_batch(B=4, T=512, K=32, seed=6)
-    batch = synth.inject_g1c(batch, np.asarray([2]), 32)
-    shape = batch["shape"]
+    from jepsen_tpu.checker.elle import cycle_anomalies_cpu
+    from jepsen_tpu.checker.elle import encode as elle_encode
+    hists = [elle_synth.synth_append_history(T=500, K=32, seed=6 + i,
+                                             g1c=(i == 2))
+             for i in range(4)]
+    encs = [elle_encode.encode_history(h) for h in hists]
+    batch = elle_kernels.pack_batch(encs)
     args = parallel.shard_batch(None, batch)
-    f_bf = parallel.sharded_check_fn(None, shape, use_pallas=False)
-    f_i8 = parallel.sharded_check_fn(None, shape, use_pallas=False,
-                                     use_int8=True)
-    bf = np.asarray(jax.block_until_ready(f_bf(*args)))
-    i8 = np.asarray(jax.block_until_ready(f_i8(*args)))
-    assert bf.tolist() == i8.tolist()
-    assert i8[2] & (1 << elle_kernels.G1C)
+    f = parallel.sharded_check_fn(None, batch["shape"])
+    words = np.asarray(jax.block_until_ready(f(*args))).tolist()
+    assert [sorted(elle_kernels.flags_to_names(w)) for w in words] \
+        == [sorted(cycle_anomalies_cpu(e)) for e in encs]
+    assert words[2] & (1 << elle_kernels.G1C)
 
 
 def test_wr_edge_batch_parity_on_device():
@@ -191,21 +164,19 @@ def test_packed_frontier_parity_on_device():
             assert bool(p) == analysis(models.cas_register(), h)["valid?"]
 
 
-def test_int8_auto_default_on_device(monkeypatch):
-    """The auto formulation must resolve to xla-int8 on hardware and
-    agree with an explicit bf16 pin verdict-for-verdict."""
+def test_int8_auto_default_on_device():
+    """The production bucket path on hardware runs the one (xla-int8)
+    formulation and agrees with the CPU oracle verdict-for-verdict."""
     from jepsen_tpu import parallel
+    from jepsen_tpu.checker.elle import cycle_anomalies_cpu
     from jepsen_tpu.checker.elle import encode as elle_encode
 
-    monkeypatch.delenv("JEPSEN_TPU_CLOSURE", raising=False)
-    d_pallas, d_int8 = elle_kernels.resolve_formulation(single_device=True)
-    assert d_int8 and not d_pallas
+    assert elle_kernels.CLOSURE_FORMULATION == "xla-int8"
     hists = [elle_synth.synth_append_history(T=300, K=8, seed=i,
                                              g1c=(i % 2 == 0))
              for i in range(4)]
     encs = [elle_encode.encode_history(h) for h in hists]
     auto = parallel.check_bucketed(encs, None)
-    monkeypatch.setenv("JEPSEN_TPU_CLOSURE", "bf16")
-    pinned = parallel.check_bucketed(encs, None)
-    assert [sorted(a) for a in auto] == [sorted(b) for b in pinned]
+    assert [sorted(a) for a in auto] \
+        == [sorted(cycle_anomalies_cpu(e)) for e in encs]
     assert sum(1 for a in auto if "G1c" in a) == 2
