@@ -26,6 +26,7 @@ closure sound; magnitudes are re-thresholded to booleans every step.
 from __future__ import annotations
 
 import functools
+import itertools
 from dataclasses import dataclass
 
 import jax
@@ -668,55 +669,93 @@ def check_batch_device(appends, reads, invoke_index, complete_index, process,
         constrain=_identity, fused=fused, with_stats=with_stats)
 
 
+def edge_matrices(edges, n_txns: int):
+    """The three [B,T,T] bool class matrices (ww, wr, rw) of packed edge
+    rows: `edges` is [B,E,3] int32 (src, dst, cls) with cls in
+    {graph.WW, WR, RW}. One scatter per history, vmapped over B so a
+    dp-sharded batch builds each history's matrices where it lives; a
+    row with any index out of range (the pack pads with T) lands
+    nowhere, and a repeated edge sets its cell once."""
+    def one(e):
+        m = jnp.zeros((3, n_txns, n_txns), bool)
+        return m.at[e[:, 2], e[:, 0], e[:, 1]].set(True, mode="drop")
+    m = jax.vmap(one)(edges)
+    return m[:, 0], m[:, 1], m[:, 2]
+
+
+def _class_matrices(arrays: tuple):
+    """(ww, wr, rw) and the timing tensors of `classify_matrices_device`'s
+    arguments, told apart by their count (static at trace time): edge
+    rows are built into matrices, T from the [B,T] timing tensors."""
+    if len(arrays) == 5:
+        edges, *timing = arrays
+        return edge_matrices(edges, timing[0].shape[-1]), timing
+    return arrays[:3], arrays[3:]
+
+
 @functools.partial(jax.jit, static_argnames=(
     "steps", "classify", "realtime", "process_order", "fused",
     "with_stats"))
-def classify_matrices_device(ww, wr, rw, invoke_index, complete_index,
-                             process, n_live, *, steps: int,
+def classify_matrices_device(*arrays, steps: int,
                              classify: bool = True, realtime: bool = False,
                              process_order: bool = False,
                              fused: bool = True,
                              with_stats: bool = False):
-    """Jitted single-device entry over packed [B,T,T] edge matrices."""
+    """Jitted single-device entry. `arrays` is (edges, invoke_index,
+    complete_index, process, n_live), `edges` the [B,E,3] packed rows
+    whose [B,T,T] matrices this same executable builds; or (ww, wr, rw,
+    invoke_index, complete_index, process, n_live) over matrices
+    already built."""
+    mats, timing = _class_matrices(arrays)
     return classify_matrices_impl(
-        ww, wr, rw, invoke_index, complete_index, process, n_live,
+        *mats, *timing,
         steps=steps, classify=classify, realtime=realtime,
         process_order=process_order, constrain=_identity, fused=fused,
         with_stats=with_stats)
 
 
-def pack_edge_matrices(per_history: list[dict], multiple: int = 128) -> dict:
-    """Pack host-built sparse edges into stacked dense bool matrices.
+def edge_pad(n_edges: int) -> int:
+    """Edge rows a bucket pads to: the next power of two, at least 1024,
+    so a sweep's buckets share few edge geometries (and executables)."""
+    return max(1024, 1 << (max(n_edges, 1) - 1).bit_length())
 
-    per_history: dicts with keys n (txn count), edges (list of
-    (src, dst, cls) with cls in {graph.WW, WR, RW}), invoke_index,
-    complete_index, process (np arrays of length n)."""
-    from . import graph as g
+
+def pack_edge_lists(per_history: list[dict], multiple: int = 128) -> dict:
+    """Pack host-built sparse edges into one [B,E,3] int32 array of
+    (src, dst, cls) rows for `edge_matrices`, plus the timing tensors.
+
+    per_history: dicts with keys n (txn count), edges ((src, dst, cls)
+    rows with cls in {graph.WW, WR, RW}), invoke_index,
+    complete_index, process (np arrays of length n). Only the three
+    dependency classes are accepted: realtime/process edges are built
+    in-kernel from the timing tensors (passing them here would
+    double-count them against the kernel's flags). Self-loops are
+    dropped; pad rows hold T in every column, so they land nowhere."""
     B = len(per_history)
     T = pad_to(max((h["n"] for h in per_history), default=1), multiple)
-    ww = np.zeros((B, T, T), bool)
-    wr = np.zeros((B, T, T), bool)
-    rw = np.zeros((B, T, T), bool)
+    rows = []
+    for hist in per_history:
+        # fromiter over the flattened rows: about twice as fast as
+        # np.asarray on a list of tuples
+        e = np.fromiter(itertools.chain.from_iterable(hist["edges"]),
+                        np.int32, 3 * len(hist["edges"])).reshape(-1, 3)
+        rows.append(e[e[:, 0] != e[:, 1]])
+    E = edge_pad(max((len(e) for e in rows), default=0))
+    edges = np.full((B, E, 3), T, np.int32)
     invoke_idx = np.zeros((B, T), np.int64)
     complete_idx = np.zeros((B, T), np.int64)
     process = np.full((B, T), -1, np.int32)
     n_live = np.zeros((B,), np.int32)
-    # Only the three dependency classes are accepted: realtime/process
-    # edges are built in-kernel from the timing tensors (passing them
-    # here would double-count them against the kernel's flags).
-    mats = {g.WW: ww, g.WR: wr, g.RW: rw}
-    for i, hist in enumerate(per_history):
+    for i, (hist, e) in enumerate(zip(per_history, rows)):
         n = hist["n"]
         n_live[i] = n
-        for s, d, cls in hist["edges"]:
-            if s != d:
-                mats[cls][i, s, d] = True
+        edges[i, :len(e)] = e
         invoke_idx[i, :n] = hist["invoke_index"]
         complete_idx[i, :n] = hist["complete_index"]
         process[i, :n] = hist["process"]
-    return {"ww": ww, "wr": wr, "rw": rw, "invoke_index": invoke_idx,
+    return {"edges": edges, "invoke_index": invoke_idx,
             "complete_index": complete_idx, "process": process,
-            "n_txns": n_live, "T": T}
+            "n_txns": n_live, "T": T, "E": E}
 
 
 def check_edge_batch(per_history: list[dict], realtime: bool = False,
@@ -743,15 +782,18 @@ def check_edge_batch(per_history: list[dict], realtime: bool = False,
     edges = sum(len(h["edges"]) for h in per_history)
     with tr.phase_span("edge_pack", B=len(per_history),
                        edges=edges) as span:
-        p = pack_edge_matrices(per_history)
+        p = pack_edge_lists(per_history)
         span.note(T=p["T"])
     tr.counter("wr_edges_packed").inc(edges)
-    names = ("ww", "wr", "rw", "invoke_index", "complete_index",
-             "process", "n_txns")
+    names = ("edges", "invoke_index", "complete_index", "process",
+             "n_txns")
+    h2d_bytes = sum(p[k].nbytes for k in names)
+    tr.counter("wr_h2d_bytes").inc(h2d_bytes)
     # device_put straight from numpy: going through jnp.asarray first
-    # would commit each [B,T,T] matrix whole onto device 0 before the
-    # dp sharding ever applied.
-    with tr.phase_span("h2d", B=len(per_history), T=p["T"]):
+    # would commit each array whole onto device 0 before the dp
+    # sharding ever applied.
+    with tr.phase_span("h2d", B=len(per_history), T=p["T"], E=p["E"],
+                       bytes=h2d_bytes):
         if len(devices) > 1:
             mesh = jax.sharding.Mesh(np.asarray(devices), ("dp",))
             sharding = jax.sharding.NamedSharding(
@@ -793,7 +835,7 @@ def check_edge_batch_bucketed(per_history: list[dict],
                               fused: bool | None = None,
                               stats_out: list | None = None) -> list[dict]:
     """check_edge_batch with device-memory-aware length bucketing: the
-    packed matrices are B·T_pad² cells × 3 edge classes, so one
+    matrices the device builds are B·T_pad² cells × 3 edge classes, so one
     unbucketed dispatch over a big store would blow HBM. Reuses
     parallel.bucket_by_length (including its dp-padding headroom —
     check_edge_batch replicates the last entry up to a device

@@ -28,7 +28,8 @@ derive dependency edges between txns:
 
 Cycle search + classification then reuses the shared machinery: CPU
 Tarjan oracle (graph.classify_cycles) or the MXU transitive-closure
-kernel over explicit edge matrices (kernels.check_edge_batch).
+kernel over edge matrices the device builds from the packed edge lists
+(kernels.check_edge_batch).
 
 Host-detected anomalies: internal (txn observes state inconsistent with
 its own prior reads/writes), G1a (read of a failed txn's write), G1b
@@ -486,7 +487,7 @@ class WrChecker(Checker):
                     stats_out: list | None = None) -> list[dict]:
         """Batched per-key dispatch: host version-order inference per
         history, then length-bucketed device cycle dispatches over the
-        packed edge matrices (kernels.check_edge_batch_bucketed);
+        packed edge lists (kernels.check_edge_batch_bucketed);
         flagged histories re-run the host oracle for witnesses.
         `stats_out` (a list) is extended with per-history kernel
         search-stat dicts on the device path (None per history on the

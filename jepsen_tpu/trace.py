@@ -89,7 +89,7 @@ DECLARED_METRICS: dict[str, frozenset] = {
         "shm_stale_reclaimed", "sidecar_upgrades", "split.native",
         "split.python", "stored_fallbacks", "warm_copy_bytes",
         "watchdog_timeouts",
-        "worker_spans", "wr_edges_packed",
+        "worker_spans", "wr_edges_packed", "wr_h2d_bytes",
     }),
     "gauges": frozenset({"donate_slots_inflight", "fleet_daemons_live",
                          "fleet_epoch", "hbm_device_bytes",

@@ -71,15 +71,16 @@ def test_north_star_check_compiles(one_chip, north_enc, classify):
 
 
 def test_wr_bucket_compiles(one_chip):
-    """The rw-register sweep's bucket: 5 histories of host-built
-    [T,T] edge matrices at T=4992, classified with the fused kernel
-    (check_edge_batch's defaults), compile for one chip and fit it."""
-    B, T = 5, 4992
+    """The rw-register sweep's bucket: 5 histories at T=4992 whose
+    packed edge rows (the larger of the sweep's two edge pads) the
+    device scatters into [T,T] matrices, classified with the fused
+    kernel (check_edge_batch's defaults), compile for one chip and
+    fit it."""
+    B, T, E = 5, 4992, K.edge_pad(4097)
 
     def s(dtype, *dims):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
-    args = (s(jnp.bool_, B, T, T), s(jnp.bool_, B, T, T),
-            s(jnp.bool_, B, T, T), s(jnp.int32, B, T),
+    args = (s(jnp.int32, B, E, 3), s(jnp.int32, B, T),
             s(jnp.int32, B, T), s(jnp.int32, B, T), s(jnp.int32, B))
     compiled = K.classify_matrices_device.lower(
         *args, steps=K.closure_steps(T), classify=True,
