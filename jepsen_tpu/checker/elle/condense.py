@@ -34,7 +34,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from ... import native_lib
+from ... import native_lib, trace
 from .encode import EncodedHistory, effective_complete_index
 from . import graph as G
 
@@ -269,9 +269,22 @@ def check_condensed(enc: EncodedHistory, *, classify: bool = True,
     [m,m] matrices are the very thing condensation avoids).
 
     `stats_out` (a list) gains one `condensed_stats` record for the
-    history — the JEPSEN_TPU_KERNEL_STATS path."""
-    members, (src, dst, cls) = condense(enc, realtime=realtime,
-                                        process_order=process_order)
+    history — the JEPSEN_TPU_KERNEL_STATS path.
+
+    Traced: a `condense` phase (args `txns`, `edges`, `sccs`,
+    `scc_max`) around the edge build and Tarjan, an `scc_host` phase
+    (arg `txns`) around each host-classified SCC, and the counters
+    `histories_condensed`, `scc_device` and `scc_host`; the per-SCC
+    device classify records its own phases."""
+    tr = trace.get_current()
+    tr.counter("histories_condensed").inc()
+    # made at zero, so a pass with no SCC of a kind reads 0, not nothing
+    n_device, n_host = tr.counter("scc_device"), tr.counter("scc_host")
+    with tr.phase_span("condense", txns=enc.n) as span:
+        members, (src, dst, cls) = condense(enc, realtime=realtime,
+                                            process_order=process_order)
+        span.note(edges=len(src), sccs=len(members),
+                  scc_max=max(map(len, members), default=0))
     if stats_out is not None:
         stats_out.append(condensed_stats(enc, members, src, dst, cls,
                                          realtime))
@@ -301,8 +314,10 @@ def check_condensed(enc: EncodedHistory, *, classify: bool = True,
     for b, rows in enumerate(members):
         keep = by_grp[bounds[b]:bounds[b + 1]]
         if len(rows) > device_scc_limit:
-            flags.update(_classify_scc_host(
-                enc, rows, src, dst, cls, keep, local, realtime))
+            n_host.inc()
+            with tr.phase_span("scc_host", txns=len(rows)):
+                flags.update(_classify_scc_host(
+                    enc, rows, src, dst, cls, keep, local, realtime))
             continue
         # PROC edges ride along as WW-class on device (same role:
         # cycle-strengthening order edges, kernels.py module doc).
@@ -317,6 +332,7 @@ def check_condensed(enc: EncodedHistory, *, classify: bool = True,
             "process": np.asarray(enc.process)[rows],
         })
     if per_scc:
+        n_device.inc(len(per_scc))
         # bucketed: many small SCCs padded to the largest one's T would
         # otherwise pack into a single over-budget [B,T,T]x3 dispatch
         # fused=False: every SCC here is cyclic by construction, so the

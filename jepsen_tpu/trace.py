@@ -77,6 +77,7 @@ DECLARED_METRICS: dict[str, frozenset] = {
         "compile_cache_hits", "compile_cache_misses", "cost_records",
         "donated_bytes", "fleet_failovers", "fleet_fences",
         "fleet_replayed_verdicts", "fleet_spills", "h2d_bytes",
+        "histories_condensed",
         "jit_compiles", "jit_lowerings", "jit_traces",
         "kernel.cyclic_histories", "kernel.stats_records",
         "native_fallback", "oom_retries", "pad_waste_cells",
@@ -84,8 +85,8 @@ DECLARED_METRICS: dict[str, frozenset] = {
         "planner.fallbacks", "planner.pred_checked",
         "quarantined", "register_cpu_routed",
         "register_keys_preencoded", "register_keys_raw", "runs_verdicted",
-        "serve_backpressure", "serve_folds", "serve_replays",
-        "serve_requests", "serve_verdicts", "shm_bytes",
+        "scc_device", "scc_host", "serve_backpressure", "serve_folds",
+        "serve_replays", "serve_requests", "serve_verdicts", "shm_bytes",
         "shm_stale_reclaimed", "sidecar_upgrades", "split.native",
         "split.python", "stored_fallbacks", "warm_copy_bytes",
         "watchdog_timeouts",
